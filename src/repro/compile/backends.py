@@ -101,11 +101,21 @@ def _relu_requant(acc, c: QConvParams, out_spec=A_SPEC):
     return Q.requantize_shift(jnp.maximum(acc, 0), c.product_exp, out_spec)
 
 
-def _float_head(h_u8, fc, in_spec=A_SPEC):
-    """Dequantize the final feature map and run pool + classifier in float —
-    identical across integer backends (the paper's host-side tail)."""
-    pooled = jnp.mean(Q.dequantize(h_u8, in_spec), axis=(1, 2))
-    return pooled @ Q.dequantize(fc.wq, fc.w_spec) + fc.b
+def _int_head(h_u8, fc, in_spec=A_SPEC):
+    """Global average pool + classifier (the paper's host-side tail), shared
+    by every integer backend.  The pool sum and the fc dot run in int32, so
+    they are exact; one float scale and the float bias follow, element by
+    element.  A logit therefore depends on its own row alone, whatever the
+    batch shape: a float pool + dot is not, on TPU its precision and
+    reduction order change with the compiled shape."""
+    _, h, w, c = h_u8.shape
+    bound = h * w * in_spec.qmax * max(-fc.w_spec.qmin, fc.w_spec.qmax) * c
+    assert bound < 2 ** 31, f"int32 head accumulator can overflow ({bound})"
+    pooled = jnp.sum(h_u8.astype(jnp.int32), axis=(1, 2))
+    acc = jnp.dot(pooled, fc.wq.astype(jnp.int32),
+                  preferred_element_type=jnp.int32)
+    scale = in_spec.scale * fc.w_spec.scale / (h * w)
+    return acc.astype(jnp.float32) * scale + fc.b
 
 
 # ---------------------------------------------------------------------------
@@ -357,8 +367,8 @@ class LaxIntBackend:
                 h = _relu_requant(
                     _int_conv(y, blk.conv1, 1, acc_init=skip_q), blk.conv1,
                     out_spec)
-            return _float_head(h, params.fc,
-                               block_outs[-1] if block_outs else stem_out)
+            return _int_head(h, params.fc,
+                             block_outs[-1] if block_outs else stem_out)
 
         return forward
 
@@ -397,8 +407,8 @@ class PallasBackend:
                     h, blk.conv0.wq, blk.conv0.bq.astype(jnp.int32),
                     blk.conv1.wq, blk.conv1.bq.astype(jnp.int32),
                     wd, bd, stride=task.stride, config=task.config, **sh)
-            return _float_head(h, params.fc,
-                               block_outs[-1] if block_outs else stem_out)
+            return _int_head(h, params.fc,
+                             block_outs[-1] if block_outs else stem_out)
 
         return forward
 
@@ -504,8 +514,8 @@ class PallasStreamBackend:
                     h, ops, specs=specs, stem=stem,
                     stem_shift=stem_shift if chain.stem is not None else None,
                     config=chain_config(chain, images.shape[0]))
-            return _float_head(h, params.fc,
-                               block_outs[-1] if block_outs else stem_out)
+            return _int_head(h, params.fc,
+                             block_outs[-1] if block_outs else stem_out)
 
         return forward
 

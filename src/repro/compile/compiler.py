@@ -14,6 +14,8 @@ Properties:
   * **Weights are closed over once.**  The lowered forward closes over the
     typed parameter pytree; XLA treats the quantized weights as constants of
     the executable, exactly like the FPGA bitstream bakes them into BRAM.
+    (The SPMD path passes them as a replicated argument instead: shard_map
+    cannot close over arrays placed on a mesh.)
   * **Fixed batch buckets.**  Each size in ``batch_sizes`` gets its own
     executable (compiled lazily on first use, or eagerly with ``eager=True``).
     A batch of n runs on the smallest bucket >= n, zero-padded; batches larger
@@ -28,6 +30,7 @@ Properties:
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Callable, Dict, Sequence, Union
 
@@ -79,7 +82,7 @@ class CompiledModel:
         self._execs: Dict[int, Callable] = {}
         self._dev_execs: Dict[tuple, Callable] = {}
         self._shard_execs: Dict[tuple, Callable] = {}
-        self._shard_lowered: Dict[tuple, Callable] = {}
+        self._shard_params: Dict[tuple, QResNetParams] = {}
         self.trace_counts: Dict[int, int] = {}
         self.compile_count = 0
 
@@ -196,14 +199,17 @@ class CompiledModel:
     def shard_executable(self, mesh, batch: int, axis: str = "data"):
         """One SPMD executable over ``mesh``: the batch dim sharded over
         ``axis`` via shard_map, weights replicated onto every mesh device
-        (``parallel.sharding.replicated_shardings``).  ``batch`` must divide
-        evenly over the axis.  This is the synchronized data-parallel path —
-        one program, all replicas in lockstep — as opposed to the replica
-        pool's independent per-device executables."""
+        (``parallel.sharding.replicated_shardings``) and passed in as a
+        ``P()`` argument.  Returns ``images -> logits``.  ``batch`` must
+        divide evenly over the axis.  This is the synchronized data-parallel
+        path — one program, all replicas in lockstep — as opposed to the
+        replica pool's independent per-device executables."""
+        from jax import shard_map
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.parallel._compat import shard_map
-        from repro.parallel.sharding import axis_size, replicated_shardings
+        from repro.parallel.sharding import (
+            auto_axes, axis_size, replicated_shardings)
 
+        mesh = auto_axes(mesh)
         n_dev = axis_size(mesh, axis)
         if batch not in self.batch_sizes:
             raise ValueError(
@@ -218,22 +224,25 @@ class CompiledModel:
         # different input shardings
         key = (int(batch), axis, tuple(mesh.shape.items()), devs)
         if key not in self._shard_execs:
-            # the weight broadcast + backend closure depend only on the
-            # mesh, not the bucket: lower once per mesh, share across
-            # buckets (the class's lowered-once contract)
+            # the weight broadcast depends only on the mesh, not the
+            # bucket: place once per mesh, share across buckets
             lkey = (axis, devs)
-            if lkey not in self._shard_lowered:
-                placed = jax.device_put(
+            if lkey not in self._shard_params:
+                self._shard_params[lkey] = jax.device_put(
                     self.params, replicated_shardings(self.params, mesh))
-                self._shard_lowered[lkey] = self.backend.lower(
-                    self.graph, self.cfg, placed)
-            smapped = shard_map(self._shard_lowered[lkey], mesh=mesh,
-                                in_specs=P(axis), out_specs=P(axis),
-                                check_vma=False)
+            placed = self._shard_params[lkey]
+
+            def body(params, images):
+                return self.backend.lower(self.graph, self.cfg,
+                                          params)(images)
+
+            smapped = shard_map(body, mesh=mesh, in_specs=(P(), P(axis)),
+                                out_specs=P(axis), check_vma=False)
             t0 = time.perf_counter()
             spec = self.input_spec(
                 batch, sharding=NamedSharding(mesh, P(axis)))
-            self._shard_execs[key] = jax.jit(smapped).lower(spec).compile()
+            exe = jax.jit(smapped).lower(placed, spec).compile()
+            self._shard_execs[key] = functools.partial(exe, placed)
             self.compile_count += 1
             self._note_compile("shard", batch, time.perf_counter() - t0)
         return self._shard_execs[key]
@@ -246,8 +255,9 @@ class CompiledModel:
         no copy guard is needed here.)"""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from repro.parallel.sharding import axis_size
+        from repro.parallel.sharding import auto_axes, axis_size
 
+        mesh = auto_axes(mesh)
         n_dev = axis_size(mesh, axis)
         fits = [b for b in self.batch_sizes if b % n_dev == 0]
         if not fits:

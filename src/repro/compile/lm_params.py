@@ -20,7 +20,7 @@ LM stream):
     run the kernel (or its bit-exact lax mirror), quantize the result onto
     the consuming matmul's input grid;
   * embed / unembed run in float (the paper's host-side head), mirroring
-    ``compile.backends._float_head``.
+    ``compile.backends._int_head``.
 
 ``init_lm_params`` generates seeded synthetic weights — the serving/
 conformance fixture; accuracy-bearing weights would come from

@@ -180,21 +180,60 @@ def conv_task_hbm_bytes(layer: ConvLayer, batch: int, batch_tile: int,
     return acts + weights
 
 
-def conv_task_vmem_bytes(layer: ConvLayer, batch_tile: int, cout_block: int,
-                         act_bytes: int = 1, w_bytes: int = 1) -> int:
-    """Per-grid-step VMEM footprint of the tiled conv kernel: the input tile
-    (floored by the eq. 16 window buffer — a step can never retain less than
-    one input window), the filter/bias slice, the int32 accumulator, and the
-    output tile."""
+# The chip's VMEM holds every array in (sublane, lane) tiles over its two
+# minor dims: (8, 128) for 32-bit data, (32, 128) for 8-bit.  The CIFAR
+# widths (3..64 channels) sit in the lane dim, so a 16-channel int32 map
+# occupies 8x its payload.  The VMEM models below count tiled bytes, for
+# the dtypes the conv kernels' datapath holds (kernels.common): u8 blocks
+# in HBM-facing tiles, int32 activations and accumulators inside, s8 tap
+# operands and filters, int32 biases.
+_LANES = 128
+
+
+def tiled_bytes(shape, itemsize: int) -> int:
+    """VMEM bytes of an array of ``shape`` with ``itemsize``-byte elements
+    once its minor two dims are padded to the chip's tile."""
+    shape = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    sub = 8 * 4 // itemsize
+    rows = -(-shape[-2] // sub) * sub
+    lanes = -(-shape[-1] // _LANES) * _LANES
+    return math.prod(shape[:-2]) * rows * lanes * itemsize
+
+
+def _filter_vmem_bytes(fh: int, fw: int, ich: int, och: int) -> int:
+    """One s8 filter + its int32 bias, double-buffered by the Pallas
+    pipeline (it allocates two buffers per blocked operand)."""
+    return 2 * (tiled_bytes((fh, fw, ich, och), 1) + tiled_bytes((och,), 4))
+
+
+def _conv_work_vmem_bytes(h: int, w: int, ich: int, och: int,
+                          stride: int = 1) -> int:
+    """Per-image int32 working set of one 3x3 conv: the padded input, one
+    tap's operand rows (int32 and their s8 narrowing), the accumulator,
+    and for stride 2 the int32 staging copy the strided taps load from."""
+    lo = 1 if stride == 1 else 0
+    xp = tiled_bytes((h + lo + 1, w + lo + 1, ich), 4)
+    rows = (h // stride) * (w // stride)
+    tap = tiled_bytes((rows, ich), 4) + tiled_bytes((rows, ich), 1)
+    acc = tiled_bytes((h // stride, w // stride, och), 4)
+    return xp * (1 if stride == 1 else 2) + tap + acc
+
+
+def conv_task_vmem_bytes(layer: ConvLayer, batch_tile: int,
+                         cout_block: int) -> int:
+    """Per-grid-step VMEM footprint of the stem conv kernel: the
+    double-buffered u8 input and output tiles, the filter/bias slice, and
+    ``batch_tile`` images' working set — every term counted as live at
+    once, an upper bound of what the compiler allocates."""
     cb = cout_block or layer.och
+    bt = batch_tile
     ihp, iwp = layer.ih + layer.fh - 1, layer.iw + layer.fw - 1
-    in_tile = max(batch_tile * ihp * iwp * layer.ich,
-                  window_buffer_size(layer.iw, layer.ich, layer.fh, layer.fw)
-                  ) * act_bytes
-    w_tile = layer.fh * layer.fw * layer.ich * cb * w_bytes + cb * 4
-    acc = layer.oh * layer.ow * cb * 4
-    out_tile = batch_tile * layer.oh * layer.ow * cb * act_bytes
-    return in_tile + w_tile + acc + out_tile
+    tiles = 2 * (tiled_bytes((bt, ihp, iwp, layer.ich), 1)
+                 + tiled_bytes((bt, layer.oh, layer.ow, cb), 1))
+    work = _conv_work_vmem_bytes(layer.ih, layer.iw, layer.ich, cb,
+                                 layer.stride)
+    return (tiles + _filter_vmem_bytes(layer.fh, layer.fw, layer.ich, cb)
+            + bt * work)
 
 
 def resblock_task_hbm_bytes(h: int, w: int, ich: int, och: int, batch: int,
@@ -216,21 +255,13 @@ def resblock_task_hbm_bytes(h: int, w: int, ich: int, och: int, batch: int,
 
 def resblock_task_vmem_bytes(h: int, w: int, ich: int, och: int,
                              batch_tile: int, downsample: bool = False,
-                             stride: int = 1, act_bytes: int = 1,
-                             w_bytes: int = 1) -> int:
-    """Per-grid-step VMEM footprint of the fused residual block: the padded
-    input tile, both filters (+ ds), and the kernel-lifetime intermediates
-    (y0, the aligned skip, and the int32 accumulator) that the fusion keeps
-    out of HBM."""
-    oh, ow = h // stride, w // stride
-    in_tile = batch_tile * (h + 2) * (w + 2) * ich * act_bytes
-    wts = (9 * ich * och + 9 * och * och
-           + (ich * och if downsample else 0)) * w_bytes + 2 * och * 4
-    y0 = (oh + 2) * (ow + 2) * och * act_bytes      # padded intermediate
-    acc = oh * ow * och * 4                          # conv accumulator
-    skip = oh * ow * och * 4                         # aligned skip stream
-    out_tile = batch_tile * oh * ow * och * act_bytes
-    return in_tile + wts + y0 + acc + skip + out_tile
+                             stride: int = 1) -> int:
+    """Per-grid-step VMEM footprint of the fused residual block: the kernel
+    runs the chain megakernel's per-block datapath, so this is a one-link
+    :func:`chain_task_vmem_bytes`."""
+    return chain_task_vmem_bytes(
+        [BlockShape(h, w, ich, och, downsample=downsample, stride=stride)],
+        batch_tile)
 
 
 # ---------------------------------------------------------------------------
@@ -313,33 +344,42 @@ def chain_task_hbm_bytes(blocks: List[BlockShape], batch: int,
 
 
 def chain_task_vmem_bytes(blocks: List[BlockShape], batch_tile: int,
-                          stem_och: int = 0, act_bytes: int = 1,
-                          w_bytes: int = 1) -> int:
+                          stem_och: int = 0) -> int:
     """Per-grid-step VMEM footprint of the chain megakernel — what decides a
-    chain cut.  The whole chain's weights are pinned for the kernel's
-    lifetime (constant-index BlockSpecs), the batch input/output tiles are
-    resident, and the streaming working set is the *maximum* over links of
-    the batch tile's per-block intermediates (padded input, padded y0, int32
-    accumulator + aligned skip): the kernel body processes its whole tile
-    per link (batched tap dots), and links execute sequentially."""
-    first = blocks[0]
-    ich0 = 3 if stem_och else first.ich
-    in_tile = batch_tile * (first.h + 2) * (first.w + 2) * ich0 * act_bytes
-    wts = sum(b.weight_bytes(w_bytes) for b in blocks)
+    chain cut and its batch tile.  The whole chain's filters are pinned for
+    the kernel's lifetime (constant-index BlockSpecs, double-buffered), the
+    u8 batch input/output tiles are double-buffered, and the streaming
+    working set is the *maximum* over links of the tile's per-block
+    intermediates (links execute sequentially): conv0's working set, the
+    padded y0, the aligned skip, and conv1's accumulator.  Every term is
+    counted as live at once and lane-padded (:func:`tiled_bytes`) — an upper
+    bound of what the compiler allocates, checked against it for ResNet20 by
+    tests/test_tpu_compile.py."""
+    first, last = blocks[0], blocks[-1]
+    bt = batch_tile
     if stem_och:
-        wts += 9 * 3 * stem_och * w_bytes + stem_och * 4
+        in_tile = (bt, first.h + 2, first.w + 2, 3)
+    else:
+        lo = 1 if first.stride == 1 else 0
+        in_tile = (bt, first.h + lo + 1, first.w + lo + 1, first.ich)
+    tiles = 2 * (tiled_bytes(in_tile, 1)
+                 + tiled_bytes((bt, last.oh, last.ow, last.och), 1))
+    wts = 0
     work = 0
     if stem_och:
-        work = (first.h * first.w * stem_och            # stem output
-                + first.h * first.w * stem_och * 4)     # stem accumulator
+        wts += _filter_vmem_bytes(3, 3, 3, stem_och)
+        work = _conv_work_vmem_bytes(first.h, first.w, 3, stem_och)
     for b in blocks:
-        per_img = ((b.h + 2) * (b.w + 2) * b.ich * act_bytes   # padded input
-                   + (b.oh + 2) * (b.ow + 2) * b.och * act_bytes  # padded y0
-                   + b.oh * b.ow * b.och * 4                   # accumulator
-                   + b.oh * b.ow * b.och * 4)                  # aligned skip
+        wts += (_filter_vmem_bytes(3, 3, b.ich, b.och)
+                + _filter_vmem_bytes(3, 3, b.och, b.och))
+        if b.downsample:
+            wts += _filter_vmem_bytes(1, 1, b.ich, b.och)
+        acc = tiled_bytes((b.oh, b.ow, b.och), 4)
+        per_img = (_conv_work_vmem_bytes(b.h, b.w, b.ich, b.och, b.stride)
+                   + tiled_bytes((b.oh + 2, b.ow + 2, b.och), 4)  # padded y0
+                   + 2 * acc)                          # aligned skip + acc1
         work = max(work, per_img)
-    out_tile = batch_tile * blocks[-1].out_bytes(act_bytes)
-    return in_tile + wts + batch_tile * work + out_tile
+    return tiles + wts + bt * work
 
 
 def resnet_block_shapes(blocks_per_stage: int, base: int = 16, img: int = 32

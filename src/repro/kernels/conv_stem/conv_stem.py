@@ -7,9 +7,9 @@ activation domain (A_SPEC).  With resblock_fused covering every residual
 block, this kernel completes Pallas coverage of the whole integer graph:
 feature maps enter HBM only between kernels, exactly once each.
 
-Input is pre-padded (1,1) by the wrapper (SAME for stride 1).  The input
-channel count is tiny (3); each grid step owns ``batch_tile`` images in VMEM
-and issues one MXU dot per filter tap, like conv2d_int8.
+The input channel count is tiny (3); each grid step owns ``batch_tile``
+images in VMEM and issues one ``(bt*H*W, Cin) x (Cin, cb)`` s8 MXU dot per
+filter tap (``kernels.common.conv_taps``, with its on-chip contract).
 
 Tiling knobs (``repro.tune.KernelConfig``): ``batch_tile`` images per grid
 step and ``cout_block`` output channels per grid step — the software
@@ -26,47 +26,38 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import requant_u8
+from repro.kernels.common import conv_taps, offset_bias, pad_same, requant
 
 
-def _kernel(x_ref, w_ref, b_ref, o_ref, *, oh, ow, shift, bt):
-    w = w_ref[...]                          # (3, 3, Cin, cb)
-    for i in range(bt):
-        xp = x_ref[i]                       # (H+2, W+2, Cin) uint8
-        acc = jnp.broadcast_to(b_ref[...].astype(jnp.int32),
-                               (oh, ow, w.shape[-1])).astype(jnp.int32)
-        for kh in range(w.shape[0]):
-            for kw in range(w.shape[1]):
-                xs = jax.lax.slice(xp, (kh, kw, 0),
-                                   (kh + oh, kw + ow, xp.shape[2]))
-                acc += jax.lax.dot(
-                    xs.reshape(oh * ow, -1).astype(jnp.int32),
-                    w[kh, kw].astype(jnp.int32),
-                    preferred_element_type=jnp.int32).reshape(oh, ow, -1)
-        o_ref[i] = requant_u8(acc, shift)
+def _kernel(x_ref, w_ref, b_ref, o_ref, *, shift):
+    bt, oh, ow, cb = o_ref.shape
+    acc = jnp.broadcast_to(b_ref[...], (bt, oh, ow, cb))
+    acc = conv_taps(x_ref[...].astype(jnp.int32), w_ref[...], acc)
+    o_ref[...] = requant(acc, shift).astype(jnp.uint8)
 
 
 def conv_stem(x, w, b, *, shift, batch_tile=1, cout_block=0, interpret=False):
-    """x: (N,H+2,W+2,Cin) uint8 pre-padded; w: (3,3,Cin,Cout) int8;
-    b: (Cout,) int32.  Returns (N,H,W,Cout) uint8 post-ReLU activations.
+    """x: (N,H,W,Cin) uint8 unpadded; w: (3,3,Cin,Cout) int8; b: (Cout,) int.
+    Returns (N,H,W,Cout) uint8 post-ReLU activations (SAME padding).
     ``batch_tile`` must divide N and ``cout_block`` must divide Cout
     (0 = maximal)."""
-    N, Hp, Wp, Cin = x.shape
+    N, H, W, Cin = x.shape
     fh, fw, _, Cout = w.shape
     bt = N if batch_tile == 0 else batch_tile
     cb = Cout if cout_block == 0 else cout_block
     assert N % bt == 0, (N, bt)
     assert Cout % cb == 0, (Cout, cb)
-    oh, ow = Hp - 2, Wp - 2
+    xp = pad_same(x, 1)
+    Hp, Wp = xp.shape[1:3]
     return pl.pallas_call(
-        functools.partial(_kernel, oh=oh, ow=ow, shift=shift, bt=bt),
+        functools.partial(_kernel, shift=shift),
         grid=(N // bt, Cout // cb),
         in_specs=[
             pl.BlockSpec((bt, Hp, Wp, Cin), lambda n, c: (n, 0, 0, 0)),
             pl.BlockSpec((fh, fw, Cin, cb), lambda n, c: (0, 0, 0, c)),
             pl.BlockSpec((cb,), lambda n, c: (c,)),
         ],
-        out_specs=pl.BlockSpec((bt, oh, ow, cb), lambda n, c: (n, 0, 0, c)),
-        out_shape=jax.ShapeDtypeStruct((N, oh, ow, Cout), jnp.uint8),
+        out_specs=pl.BlockSpec((bt, H, W, cb), lambda n, c: (n, 0, 0, c)),
+        out_shape=jax.ShapeDtypeStruct((N, H, W, Cout), jnp.uint8),
         interpret=interpret,
-    )(x, w, b)
+    )(xp, w, offset_bias(b, w))

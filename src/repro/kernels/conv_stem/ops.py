@@ -2,7 +2,6 @@
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels.common import use_interpret
 from repro.kernels.conv_stem.conv_stem import conv_stem
@@ -11,12 +10,10 @@ from repro.tune.config import DEFAULT, KernelConfig
 
 @partial(jax.jit, static_argnames=("shift", "config"))
 def conv_stem_op(x, w, b, *, shift, config: KernelConfig = None):
-    """x: (N,H,W,Cin) uint8 (unpadded); SAME 3x3 padding applied here.
+    """x: (N,H,W,Cin) uint8 (unpadded; the kernel applies SAME padding).
     b may be int16 (bias_spec) — widened to the int32 accumulator dtype.
     ``config`` carries the tuned tiling knobs (default: one image per grid
     step, all output channels in one block)."""
     cfg = (config or DEFAULT).normalize(x.shape[0], w.shape[-1])
-    xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)))
-    return conv_stem(xp, w, b.astype(jnp.int32), shift=shift,
-                     batch_tile=cfg.batch_tile, cout_block=cfg.cout_block,
-                     interpret=use_interpret())
+    return conv_stem(x, w, b, shift=shift, batch_tile=cfg.batch_tile,
+                     cout_block=cfg.cout_block, interpret=use_interpret())

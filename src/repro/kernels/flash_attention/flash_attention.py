@@ -32,12 +32,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, bq, bk, hd, causal, kv_len,
 
     def step(j, carry):
         m, l, acc = carry
-        # index the leading dim with a size-1 slice (a bare int trips the
-        # pallas indexer on older jax), then drop it
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(j * bk, bk),
-                            slice(None)))[0].astype(jnp.float32)  # (bk, hd)
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(j * bk, bk),
-                            slice(None)))[0].astype(jnp.float32)
+        k = k_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)  # (bk, hd)
+        v = v_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)
         s = q @ k.T                                           # (bq, bk)
         if causal:
             k_pos = j * bk + jnp.arange(bk)

@@ -35,7 +35,7 @@ def attention_ref(q, k, v, *, causal=True):
 def flash_attention_mirror(q, k, v, *, causal=True, bq=128, bk=128):
     """The flash kernel's arithmetic, op-for-op, without pallas: q tiles in
     a python loop (the grid dim), K/V tiles via dynamic_slice (the kernel's
-    ``pl.load`` walk), the identical online-softmax update per step."""
+    ``pl.ds`` walk), the identical online-softmax update per step."""
     BH, Sq, hd = q.shape
     Sk = k.shape[1]
     bq = min(bq, Sq)

@@ -29,17 +29,16 @@ Chain legality:
 * the batch-grid input/output tiles keep grid-varying index maps, so Pallas's
   automatic pipelining double-buffers the HBM activation traffic that remains.
 
-Per-block arithmetic is the batched twin of ``resblock_fused.block_body``:
-the chain holds its whole batch tile in VMEM, so each filter tap is ONE
-``(bt*oh*ow, Cin) x (Cin, Cout)`` dot across every image of the tile instead
-of ``bt`` per-image dots — larger MXU contractions from the same adds/muls,
-so the result is bit-exact with the per-block pipeline by construction
-(asserted over every legal partition in the conformance suite).  In
-interpret mode (CPU emulation) each tap contraction additionally runs
-through the exact float32 fast path of :func:`_dot_i32` — bit-identical
+Per-block arithmetic is ``kernels.common.block_body`` — the datapath
+``resblock_fused`` runs too — so the result is bit-exact with the per-block
+pipeline by construction (asserted over every legal partition in the
+conformance suite).  Each filter tap is ONE ``(bt*oh*ow, Cin) x (Cin, Cout)``
+s8 dot across every image of the tile.  Stride-2 links stage their padded
+input in an int32 VMEM scratch (one per distinct shape) for the strided tap
+loads.  In interpret mode (CPU emulation) each tap contraction additionally
+runs through the exact float32 fast path of :func:`_dot_i32` — bit-identical
 below the statically-guarded 2^24 bound, but on XLA:CPU's vectorized GEMM
-instead of its scalar integer loops, which is where the streamed chain's
-measured FPS edge over the per-block pipeline comes from off-TPU.
+instead of its scalar integer loops.
 
 Tiling knob (``repro.tune.KernelConfig``): ``batch_tile`` images per grid
 step — the ``pallas-stream`` backend defaults it to the *largest* VMEM-legal
@@ -56,9 +55,11 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.quant import shift_align
-from repro.kernels.common import requant_u8
+from repro.kernels.common import (
+    ACT_OFFSET, block_body, conv_taps, dot_u8s8, offset_bias, pad_same,
+    requant)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -73,175 +74,120 @@ class ChainBlockSpec:
     skip_shift: int
 
 
-def _pad_lo(stride: int) -> int:
-    # lax SAME for a 3x3 conv: (1, 1) at stride 1, (0, 1) at stride 2
-    return 1 if stride == 1 else 0
-
-
-def _pad_for(h, stride: int):
-    """Re-pad a (bt, H, W, C) activation in VMEM with the next conv's SAME
-    convention."""
-    lo = _pad_lo(stride)
-    return jnp.pad(h, ((0, 0), (lo, 1), (lo, 1), (0, 0)))
-
-
-# Longest u8 x s8 contraction whose dot is exact in float32: every partial
-# sum is an integer and the largest magnitude, rows * 127 * 255, must stay
-# below 2^24 (f32 integer-exactness bound).  517 — comfortably above the
-# widest chain link (Cin = 64).
-F32_EXACT_ROWS = (1 << 24) // (127 * 255)
+# Longest offset-s8 contraction whose dot is exact in float32: every partial
+# sum is an integer of magnitude at most rows * 128 * 128, which must not
+# pass 2^24 (f32 integer-exactness bound).  1024 — far above the widest
+# chain link (Cin = 64).
+F32_EXACT_ROWS = (1 << 24) // (ACT_OFFSET * ACT_OFFSET)
 
 
 def _dot_i32(rows, wm, fast_emul):
-    """``(M, K) u8-valued x (K, Cout) s8-valued -> (M, Cout) int32``, exact.
+    """``(M, K)`` u8-valued rows x ``(K, Cout)`` s8 -> ``(M, Cout)`` int32
+    ``(rows - ACT_OFFSET) @ wm``, exact.
 
-    The TPU path feeds the MXU an int32-accumulated integer dot.  Under
-    ``fast_emul`` (interpret mode, i.e. CPU emulation) the SAME contraction
-    runs in float32 — XLA:CPU lowers integer GEMMs to scalar loops but float
-    GEMMs to the vectorized Eigen path, ~3-4x faster.  Exactness is not
-    probabilistic: every partial sum is an integer below 2^24 (guarded by
+    The TPU path is :func:`~repro.kernels.common.dot_u8s8`, one s8 MXU dot.
+    Under ``fast_emul`` (interpret mode, i.e. CPU emulation) the SAME
+    contraction runs in float32 — XLA:CPU lowers integer GEMMs to scalar
+    loops but float GEMMs to the vectorized Eigen path.  Exactness is not
+    probabilistic: every partial sum is an integer within 2^24 (guarded by
     :data:`F32_EXACT_ROWS` at trace time), where float32 arithmetic is
     exact, so the rounded-back int32 result is bit-identical."""
+    rows = rows.astype(jnp.int32)
     if fast_emul and rows.shape[1] <= F32_EXACT_ROWS:
-        return jax.lax.dot(rows.astype(jnp.float32),
+        return jax.lax.dot((rows - ACT_OFFSET).astype(jnp.float32),
                            wm.astype(jnp.float32)).astype(jnp.int32)
-    return jax.lax.dot(rows.astype(jnp.int32), wm.astype(jnp.int32),
-                       preferred_element_type=jnp.int32)
+    return dot_u8s8(rows, wm)
 
 
-def _conv_taps(x, w, oh, ow, acc, stride=1, fast_emul=False):
-    """3x3 tap-wise conv over a whole (bt, Hp, Wp, Cin) batch tile: each tap
-    is a single ``(bt*oh*ow, Cin) x (Cin, Cout)`` dot — the batched twin of
-    ``resblock_fused._conv_tap_acc`` (one contraction per tap instead of
-    ``bt``), accumulated tap-by-tap in int32."""
-    bt = x.shape[0]
-    fh, fw = w.shape[0], w.shape[1]
-    for kh in range(fh):
-        for kw in range(fw):
-            xs = jax.lax.slice(x, (0, kh, kw, 0),
-                               (bt, kh + (oh - 1) * stride + 1,
-                                kw + (ow - 1) * stride + 1, x.shape[3]),
-                               (1, stride, stride, 1))
-            acc += _dot_i32(xs.reshape(bt * oh * ow, -1), w[kh, kw],
-                            fast_emul).reshape(bt, oh, ow, -1)
-    return acc
-
-
-def _block_body(xp, w0, b0, w1, b1, wd, bd, *, stride, shift0, shift1,
-                skip_shift, fast_emul=False):
-    """One residual block on a (bt, Hp, Wp, Cin) padded batch tile — the
-    batched twin of ``resblock_fused.block_body``, element-for-element the
-    same integer arithmetic."""
-    has_ds = wd is not None
-    pad_lo = _pad_lo(stride)
-    bt = xp.shape[0]
-    oh = (xp.shape[1] - 3) // stride + 1
-    ow = (xp.shape[2] - 3) // stride + 1
-    co = b0.shape[0]
-    # conv0 (strided) + relu + requant, all in VMEM
-    acc0 = jnp.broadcast_to(b0.astype(jnp.int32),
-                            (bt, oh, ow, co)).astype(jnp.int32)
-    acc0 = _conv_taps(xp, w0, oh, ow, acc0, stride, fast_emul)
-    y0 = requant_u8(acc0, shift0)
-    y0p = _pad_for(y0, 1)
-    # skip stream, rescaled into conv1's product domain
-    if has_ds:
-        xs = jax.lax.slice(xp, (0, pad_lo, pad_lo, 0),
-                           (bt, pad_lo + (oh - 1) * stride + 1,
-                            pad_lo + (ow - 1) * stride + 1, xp.shape[3]),
-                           (1, stride, stride, 1))
-        accd = _dot_i32(xs.reshape(bt * oh * ow, -1), wd[0, 0],
-                        fast_emul).reshape(bt, oh, ow, -1)
-        skip = shift_align(accd + bd.astype(jnp.int32), skip_shift)
-    else:
-        xs = jax.lax.slice(xp, (0, pad_lo, pad_lo, 0),
-                           (bt, pad_lo + oh, pad_lo + ow, xp.shape[3]))
-        skip = shift_align(xs, skip_shift)
-    # conv1 with add-fold: skip initializes the accumulator
-    acc1 = skip + b1.astype(jnp.int32)
-    acc1 = _conv_taps(y0p, w1, oh, ow, acc1, 1, fast_emul)
-    return requant_u8(acc1, shift1)
-
-
-def _kernel(*refs, specs: Tuple[ChainBlockSpec, ...], stem_shift, bt,
-            fast_emul):
-    """refs = (x, [stem_w, stem_b,] per-block weights..., out).  The
-    per-block weight refs are (w0, b0, w1, b1[, wd, bd]) — downsample
+def _kernel(*refs, specs: Tuple[ChainBlockSpec, ...], stem_shift, n_in,
+            stage_of, fast_emul):
+    """refs = (x, [stem_w, stem_b,] per-block weights..., out, stages...).
+    The per-block weight refs are (w0, b0, w1, b1[, wd, bd]) — downsample
     operands present only for ``has_ds`` links (the static specs drive the
-    unflattening, so identity blocks ship no zero tensors)."""
-    it = iter(refs[:-1])
-    x_ref, o_ref = refs[0], refs[-1]
-    next(it)                                      # consume x_ref
-    stem = (next(it), next(it)) if stem_shift is not None else None
-    blocks = []
-    for s in specs:
-        ws = [next(it) for _ in range(6 if s.has_ds else 4)]
-        if not s.has_ds:
-            ws += [None, None]                    # identity skip: no wd/bd
-        blocks.append(ws)
+    unflattening, so identity blocks ship no zero tensors).  ``stage_of[j]``
+    indexes link j's int32 staging scratch (stride-2 links only)."""
+    x_ref, o_ref, stages = refs[0], refs[n_in], refs[n_in + 1:]
+    it = iter(refs[1:n_in])
+    dot = functools.partial(_dot_i32, fast_emul=fast_emul)
 
-    h = x_ref[...]                                # (bt,Hp,Wp,C) chain input
-    if stem is not None:
-        sw, sb = stem[0][...], stem[1][...]
-        oh, ow = h.shape[1] - 2, h.shape[2] - 2
-        acc = jnp.broadcast_to(sb.astype(jnp.int32),
-                               (bt, oh, ow, sw.shape[-1])).astype(jnp.int32)
-        acc = _conv_taps(h, sw, oh, ow, acc, 1, fast_emul)
+    h = x_ref[...].astype(jnp.int32)              # (bt,Hp,Wp,C) chain input
+    if stem_shift is not None:
+        sw, sb = next(it)[...], next(it)[...]
+        bt, hp, wp = h.shape[:3]
+        acc = jnp.broadcast_to(sb, (bt, hp - 2, wp - 2, sw.shape[-1]))
         # the stem output is re-padded IN VMEM for the first block — the
         # boundary that per-kernel execution pays through HBM
-        h = _pad_for(requant_u8(acc, stem_shift), specs[0].stride)
-    for j, (s, ws) in enumerate(zip(specs, blocks)):
-        y = _block_body(
-            h, *(w[...] if w is not None else None for w in ws),
-            stride=s.stride, shift0=s.shift0, shift1=s.shift1,
-            skip_shift=s.skip_shift, fast_emul=fast_emul)
+        h = pad_same(requant(conv_taps(h, sw, acc, 1, dot), stem_shift),
+                     specs[0].stride)
+    for j, s in enumerate(specs):
+        ws = [next(it)[...] for _ in range(6 if s.has_ds else 4)]
+        if not s.has_ds:
+            ws += [None, None]                    # identity skip: no wd/bd
+        y = block_body(
+            h, *ws, stride=s.stride, shift0=s.shift0, shift1=s.shift1,
+            skip_shift=s.skip_shift, dot=dot,
+            stage=stages[stage_of[j]] if stage_of[j] is not None else None)
         if j + 1 < len(specs):                    # inter-block VMEM re-pad
-            h = _pad_for(y, specs[j + 1].stride)
-    o_ref[...] = y
+            h = pad_same(y, specs[j + 1].stride)
+    o_ref[...] = y.astype(jnp.uint8)
 
 
 def block_chain(x, blocks, *, specs: Tuple[ChainBlockSpec, ...],
                 stem=None, stem_shift: Optional[int] = None,
                 batch_tile: int = 1, interpret: bool = False):
-    """x: (N,Hp,Wp,Cin) uint8, pre-padded with the first op's SAME convention
-    ((1,1) when the stem is fused — the stem is stride 1 — else per
-    ``specs[0].stride``).  ``blocks``: one (w0,b0,w1,b1[,wd,bd]) tuple per
-    chain link, biases int32; ``stem``: optional (w, b) fused at the chain
-    head.  Returns the last block's (N,oh,ow,Cout) uint8 output; every
-    intermediate activation lives and dies in VMEM."""
+    """x: (N,H,W,Cin) uint8 unpadded, H and W even — the image batch when
+    ``stem`` is fused, else the previous kernel's activation.  ``blocks``:
+    one (w0,b0,w1,b1[,wd,bd]) tuple per chain link; ``stem``: optional
+    (w, b) fused at the chain head.  Returns the last block's (N,oh,ow,Cout)
+    uint8 output; every intermediate activation lives and dies in VMEM."""
     assert len(blocks) == len(specs) and specs, (len(blocks), len(specs))
-    N, Hp, Wp, _ = x.shape
+    N, oh, ow, _ = x.shape
     bt = N if batch_tile == 0 else batch_tile
     assert N % bt == 0, (N, bt)
+    first_stride = 1 if stem is not None else specs[0].stride
+    # the (0, 1) stride-2 padding matches lax SAME only for even dims
+    assert first_stride == 1 or (oh % 2 == 0 and ow % 2 == 0), \
+        "stride-2 chain head requires even H/W to match lax SAME padding"
+    xp = pad_same(x, first_stride)
 
-    operands = [x]
+    operands = [xp]
     if stem is not None:
         assert stem_shift is not None
-        operands += list(stem)
-        oh, ow = Hp - 2, Wp - 2                   # stem is 3x3 stride 1
+        operands += [stem[0], offset_bias(stem[1], stem[0])]
     else:
         assert stem_shift is None
-        lo = _pad_lo(specs[0].stride)
-        oh, ow = Hp - lo - 1, Wp - lo - 1         # undo the first op's pad
+    stage_shapes, stage_of = [], []
     for s, ws in zip(specs, blocks):
         assert len(ws) == (6 if s.has_ds else 4), (s, len(ws))
-        operands += list(ws)
+        w0, b0, w1, b1 = ws[:4]
+        operands += [w0, offset_bias(b0, w0), w1, offset_bias(b1, w1)]
+        if s.has_ds:
+            operands += [ws[4], offset_bias(ws[5], ws[4])]
+        stage = None
+        if s.stride != 1:
+            # one int32 staging scratch per distinct padded input shape
+            shape = (bt, oh + 1, ow + 1, w0.shape[2])
+            if shape not in stage_shapes:
+                stage_shapes.append(shape)
+            stage = stage_shapes.index(shape)
+        stage_of.append(stage)
         oh, ow = oh // s.stride, ow // s.stride   # SAME conv on even dims
     cout = blocks[-1][2].shape[-1]                # w1: (3,3,Cout,Cout)
 
-    in_specs = [pl.BlockSpec((bt, Hp, Wp, x.shape[3]),
-                             lambda n: (n, 0, 0, 0))]
+    in_specs = [pl.BlockSpec((bt,) + xp.shape[1:], lambda n: (n, 0, 0, 0))]
     # chain weights: constant index maps — fetched once, pinned in VMEM
     # across every batch-grid step (the planner guarantees they fit)
     for op in operands[1:]:
         in_specs.append(pl.BlockSpec(op.shape,
                                      lambda n, d=op.ndim: (0,) * d))
     return pl.pallas_call(
-        functools.partial(_kernel, specs=specs, stem_shift=stem_shift, bt=bt,
+        functools.partial(_kernel, specs=specs, stem_shift=stem_shift,
+                          n_in=len(operands), stage_of=tuple(stage_of),
                           fast_emul=interpret),
         grid=(N // bt,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bt, oh, ow, cout), lambda n: (n, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((N, oh, ow, cout), jnp.uint8),
+        scratch_shapes=[pltpu.VMEM(s, jnp.int32) for s in stage_shapes],
         interpret=interpret,
     )(*operands)

@@ -2,11 +2,9 @@
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels.common import use_interpret
-from repro.kernels.megakernel.megakernel import (
-    ChainBlockSpec, _pad_lo, block_chain)
+from repro.kernels.megakernel.megakernel import block_chain
 from repro.tune.config import DEFAULT, KernelConfig
 
 
@@ -17,23 +15,11 @@ def block_chain_op(x, blocks, *, specs, stem=None, stem_shift=None,
     ``stem`` is fused, else the previous kernel's activation.  ``blocks`` is
     one (w0,b0,w1,b1[,wd,bd]) array tuple per chain link and ``specs`` the
     matching static :class:`ChainBlockSpec` schedule; SAME padding for the
-    chain's first op is applied here, every later pad happens in VMEM inside
-    the kernel.  ``config`` carries the tuned ``batch_tile`` (``cout_block``
-    is fusion-illegal, as for ``resblock_fused``)."""
-    first_stride = 1 if stem is not None else specs[0].stride
-    # the (0, 1) stride-2 padding matches lax SAME only for even spatial
-    # dims; ResNet8/20 maps are always even (same guard as resblock_fused_op)
-    assert first_stride == 1 or (x.shape[1] % 2 == 0
-                                 and x.shape[2] % 2 == 0), \
-        "stride-2 chain head requires even H/W to match lax SAME padding"
-    lo = _pad_lo(first_stride)
-    xp = jnp.pad(x, ((0, 0), (lo, 1), (lo, 1), (0, 0)))
+    chain's first op is applied by the kernel wrapper, every later pad
+    happens in VMEM inside the kernel.  ``config`` carries the tuned
+    ``batch_tile`` (``cout_block`` is fusion-illegal, as for
+    ``resblock_fused``)."""
     cfg = (config or DEFAULT).normalize(x.shape[0], blocks[-1][2].shape[-1])
-    blocks = tuple(
-        tuple(w if w.dtype == jnp.int8 else w.astype(jnp.int32) for w in ws)
-        for ws in blocks)
-    if stem is not None:
-        stem = (stem[0], stem[1].astype(jnp.int32))
-    return block_chain(xp, blocks, specs=specs, stem=stem,
+    return block_chain(x, blocks, specs=specs, stem=stem,
                        stem_shift=stem_shift, batch_tile=cfg.batch_tile,
                        interpret=use_interpret())

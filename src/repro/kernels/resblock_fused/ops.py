@@ -2,18 +2,10 @@
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels.common import use_interpret
 from repro.kernels.resblock_fused.resblock_fused import resblock_fused
 from repro.tune.config import DEFAULT, KernelConfig
-
-
-def _same_pad(x, stride):
-    """SAME padding of a 3x3 conv as jax.lax computes it: (1, 1) for
-    stride 1; (0, 1) for stride 2 (total pad 1, low gets pad_total // 2)."""
-    lo = 1 if stride == 1 else 0
-    return jnp.pad(x, ((0, 0), (lo, 1), (lo, 1), (0, 0)))
 
 
 @partial(jax.jit,
@@ -22,16 +14,12 @@ def _same_pad(x, stride):
 def resblock_fused_op(x, w0, b0, w1, b1, wd=None, bd=None, *, stride=1,
                       shift0, shift1, skip_shift=0,
                       config: KernelConfig = None):
-    """x: (N,H,W,Cin) uint8 (unpadded).  SAME 3x3 padding applied here.
+    """x: (N,H,W,Cin) uint8 (unpadded; the kernel applies SAME padding).
     Pass wd/bd to fuse the 1x1 downsample conv on the skip path.  ``config``
     carries the tuned ``batch_tile`` (channel blocking is illegal for the
     fused block — see the kernel docstring)."""
-    # the (0, 1) stride-2 padding below matches lax SAME only for even
-    # spatial dims (odd dims pad (1, 1)); ResNet8/20 maps are always even
-    assert stride == 1 or (x.shape[1] % 2 == 0 and x.shape[2] % 2 == 0), \
-        "stride-2 fused block requires even H/W to match lax SAME padding"
     cfg = (config or DEFAULT).normalize(x.shape[0], w1.shape[-1])
-    return resblock_fused(_same_pad(x, stride), w0, b0, w1, b1, wd, bd,
-                          stride=stride, shift0=shift0, shift1=shift1,
+    return resblock_fused(x, w0, b0, w1, b1, wd, bd, stride=stride,
+                          shift0=shift0, shift1=shift1,
                           skip_shift=skip_shift, batch_tile=cfg.batch_tile,
                           interpret=use_interpret())

@@ -18,17 +18,18 @@ Covers every block shape of ResNet8/20:
   shift-aligned from the ds product domain into conv1's product domain and
   folded into conv1's accumulator.  The downsampled skip never exists in HBM.
 
-Padding convention (must match ``jax.lax`` SAME): the caller pre-pads the
-input with ``pad_lo = 1, pad_hi = 1`` for stride 1 and ``pad_lo = 0,
-pad_hi = 1`` for stride 2 (lax splits the 1-row SAME padding of a stride-2
-3x3 conv as (0, 1)).
+The arithmetic is ``kernels.common.block_body`` — the datapath shared with
+the block-chain megakernel, with its on-chip contract (s8 offset dots, int32
+staging for strided taps).  Padding follows ``jax.lax`` SAME: (1, 1) at
+stride 1, (0, 1) at stride 2.
 
 Tiling knob (``repro.tune.KernelConfig``): ``batch_tile`` images per grid
-step — larger tiles amortize the per-step weight reload.  Grid: (N/bt,).
-Channel blocking is structurally illegal here: conv1 consumes *all* of
-conv0's output channels, so splitting Cout across grid steps would force the
-intermediate y0 back through HBM — exactly the traffic the fusion removes.
-``tune.space`` therefore never enumerates ``cout_block`` for this kernel.
+step — larger tiles amortize the per-step weight reload and feed each tap
+dot ``bt`` images' rows.  Grid: (N/bt,).  Channel blocking is structurally
+illegal here: conv1 consumes *all* of conv0's output channels, so splitting
+Cout across grid steps would force the intermediate y0 back through HBM —
+exactly the traffic the fusion removes.  ``tune.space`` therefore never
+enumerates ``cout_block`` for this kernel.
 """
 from __future__ import annotations
 
@@ -37,122 +38,61 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.quant import shift_align
-from repro.kernels.common import requant_u8
-
-
-def _conv_tap_acc(x, w, oh, ow, acc, stride=1):
-    # activations are uint8 (post-ReLU, unsigned per eq. 2/3), weights int8;
-    # widen to int32 for the dot — on TPU the MXU consumes the u8/s8 operands
-    # natively (preferred_element_type drives the int32 accumulate).
-    fh, fw = w.shape[0], w.shape[1]
-    for kh in range(fh):
-        for kw in range(fw):
-            xs = jax.lax.slice(x, (kh, kw, 0),
-                               (kh + (oh - 1) * stride + 1,
-                                kw + (ow - 1) * stride + 1, x.shape[2]),
-                               (stride, stride, 1))
-            acc += jax.lax.dot(
-                xs.reshape(oh * ow, -1).astype(jnp.int32),
-                w[kh, kw].astype(jnp.int32),
-                preferred_element_type=jnp.int32).reshape(oh, ow, -1)
-    return acc
+from repro.kernels.common import block_body, offset_bias, pad_same
 
 
-def block_body(xp, w0, b0, w1, b1, wd, bd, *, stride, shift0, shift1,
-               skip_shift):
-    """One residual block on a single image's *padded* activation ``xp``
-    (``(Hp, Wp, Cin)`` uint8, the module's SAME convention): conv0 (strided)
-    -> ReLU/requant -> [fused 1x1 downsample] skip align -> conv1 with the
-    skip initializing its accumulator -> ReLU/requant.  Everything stays in
-    registers/VMEM; returns the unpadded ``(oh, ow, Cout)`` uint8 output.
-
-    This is the shared streaming datapath: ``resblock_fused`` runs it once
-    per image, the block-chain ``megakernel`` runs a whole sequence of them
-    back to back without the activation ever leaving VMEM."""
-    has_ds = wd is not None
-    pad_lo = 1 if stride == 1 else 0
-    oh = (xp.shape[0] - 3) // stride + 1
-    ow = (xp.shape[1] - 3) // stride + 1
-    co = b0.shape[0]
-    # ---- conv0 (strided) + relu + requant (stays in VMEM) ----
-    acc0 = jnp.broadcast_to(b0.astype(jnp.int32),
-                            (oh, ow, co)).astype(jnp.int32)
-    acc0 = _conv_tap_acc(xp, w0, oh, ow, acc0, stride)
-    y0 = requant_u8(acc0, shift0)                       # (oh,ow,Cout)
-    y0p = jnp.pad(y0, ((1, 1), (1, 1), (0, 0)))
-    # ---- skip stream, rescaled into conv1's product domain ----
-    if has_ds:
-        # fused 1x1 downsample conv: SAME padding of a 1x1 conv is zero,
-        # so output o reads x[o*stride] = xp[pad_lo + o*stride]
-        xs = jax.lax.slice(xp, (pad_lo, pad_lo, 0),
-                           (pad_lo + (oh - 1) * stride + 1,
-                            pad_lo + (ow - 1) * stride + 1, xp.shape[2]),
-                           (stride, stride, 1))         # (oh,ow,Cin)
-        accd = jax.lax.dot(
-            xs.reshape(oh * ow, -1).astype(jnp.int32),
-            wd[0, 0].astype(jnp.int32),
-            preferred_element_type=jnp.int32).reshape(oh, ow, -1)
-        accd = accd + bd.astype(jnp.int32)
-        skip = shift_align(accd, skip_shift)
-    else:
-        xs = jax.lax.slice(xp, (pad_lo, pad_lo, 0),
-                           (pad_lo + oh, pad_lo + ow, xp.shape[2]))
-        skip = shift_align(xs, skip_shift)
-    # ---- conv1 with add-fold: skip initializes the accumulator ----
-    acc1 = skip + b1.astype(jnp.int32)
-    acc1 = _conv_tap_acc(y0p, w1, oh, ow, acc1)
-    return requant_u8(acc1, shift1)
-
-
-def _kernel(x_ref, w0_ref, b0_ref, w1_ref, b1_ref, wd_ref, bd_ref, o_ref, *,
-            stride, shift0, shift1, skip_shift, has_ds, bt):
-    for i in range(bt):
-        o_ref[i] = block_body(
-            x_ref[i], w0_ref[...], b0_ref[...], w1_ref[...], b1_ref[...],
-            wd_ref[...] if has_ds else None,
-            bd_ref[...] if has_ds else None,
-            stride=stride, shift0=shift0, shift1=shift1,
-            skip_shift=skip_shift)
+def _kernel(x_ref, w0_ref, b0_ref, w1_ref, b1_ref, *rest, stride, shift0,
+            shift1, skip_shift, has_ds):
+    wd_ref, bd_ref = rest[:2] if has_ds else (None, None)
+    o_ref, *stage = rest[2:] if has_ds else rest
+    y = block_body(
+        x_ref[...].astype(jnp.int32), w0_ref[...], b0_ref[...], w1_ref[...],
+        b1_ref[...], wd_ref[...] if has_ds else None,
+        bd_ref[...] if has_ds else None, stride=stride, shift0=shift0,
+        shift1=shift1, skip_shift=skip_shift,
+        stage=stage[0] if stage else None)
+    o_ref[...] = y.astype(jnp.uint8)
 
 
 def resblock_fused(x, w0, b0, w1, b1, wd=None, bd=None, *, stride=1,
                    shift0, shift1, skip_shift=0, batch_tile=1,
                    interpret=False):
-    """x: (N,Hp,Wp,Cin) uint8 pre-padded per the module's SAME convention;
-    w0: (3,3,Cin,Cout) int8; w1: (3,3,Cout,Cout) int8; b0/b1: (Cout,) int32;
-    wd: (1,1,Cin,Cout) int8 + bd: (Cout,) int32 for the fused downsample skip
-    (None for identity skip).  shift0/shift1: pow2 requant shifts (positive =
-    right shift); skip_shift: signed product-domain alignment shift.
-    ``batch_tile`` images per grid step (0 = whole batch, must divide N).
-    Returns (N,oh,ow,Cout) uint8."""
-    N, Hp, Wp, Cin = x.shape
+    """x: (N,H,W,Cin) uint8 unpadded, H and W even; w0: (3,3,Cin,Cout) int8;
+    w1: (3,3,Cout,Cout) int8; b0/b1: (Cout,) int; wd: (1,1,Cin,Cout) int8 +
+    bd: (Cout,) int for the fused downsample skip (None for identity skip).
+    shift0/shift1: pow2 requant shifts (positive = right shift); skip_shift:
+    signed product-domain alignment shift.  ``batch_tile`` images per grid
+    step (0 = whole batch, must divide N).  Returns (N,H/s,W/s,Cout) uint8."""
+    N, H, W, Cin = x.shape
     Cout = w0.shape[-1]
     has_ds = wd is not None
     bt = N if batch_tile == 0 else batch_tile
     assert N % bt == 0, (N, bt)
-    oh = (Hp - 3) // stride + 1
-    ow = (Wp - 3) // stride + 1
+    # the (0, 1) stride-2 padding matches lax SAME only for even dims
+    assert stride == 1 or (H % 2 == 0 and W % 2 == 0), \
+        "stride-2 fused block requires even H/W to match lax SAME padding"
     if not has_ds:
         assert stride == 1 and Cin == Cout, "identity skip needs stride 1"
-        wd = jnp.zeros((1, 1, Cin, Cout), jnp.int8)
-        bd = jnp.zeros((Cout,), jnp.int32)
+    xp = pad_same(x, stride)
+    Hp, Wp = xp.shape[1:3]
+    oh, ow = H // stride, W // stride
+    operands = [xp, w0, offset_bias(b0, w0), w1, offset_bias(b1, w1)]
+    if has_ds:
+        operands += [wd, offset_bias(bd, wd)]
+    in_specs = [pl.BlockSpec((bt, Hp, Wp, Cin), lambda n: (n, 0, 0, 0))]
+    in_specs += [pl.BlockSpec(op.shape, lambda n, d=op.ndim: (0,) * d)
+                 for op in operands[1:]]
+    stage = [pltpu.VMEM((bt, Hp, Wp, Cin), jnp.int32)] if stride != 1 else []
     return pl.pallas_call(
         functools.partial(_kernel, stride=stride, shift0=shift0,
-                          shift1=shift1, skip_shift=skip_shift, has_ds=has_ds,
-                          bt=bt),
+                          shift1=shift1, skip_shift=skip_shift,
+                          has_ds=has_ds),
         grid=(N // bt,),
-        in_specs=[
-            pl.BlockSpec((bt, Hp, Wp, Cin), lambda n: (n, 0, 0, 0)),
-            pl.BlockSpec(w0.shape, lambda n: (0,) * 4),
-            pl.BlockSpec(b0.shape, lambda n: (0,)),
-            pl.BlockSpec(w1.shape, lambda n: (0,) * 4),
-            pl.BlockSpec(b1.shape, lambda n: (0,)),
-            pl.BlockSpec(wd.shape, lambda n: (0,) * 4),
-            pl.BlockSpec(bd.shape, lambda n: (0,)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((bt, oh, ow, Cout), lambda n: (n, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((N, oh, ow, Cout), jnp.uint8),
+        scratch_shapes=stage,
         interpret=interpret,
-    )(x, w0, b0, w1, b1, wd, bd)
+    )(*operands)

@@ -22,22 +22,15 @@ def _kernel(u_ref, dt_ref, a_ref, b_ref, c_ref, h0_ref, y_ref, hout_ref, *,
     h = h0_ref[0]                    # (bd, N)
 
     def step(t, h):
-        # t is a traced loop index: load through pl.load + pl.dslice — a
-        # bare ``ref[0, t]`` is the int-index pattern that trips the pallas
-        # indexer outside interpret mode (the PR-1 bug class)
-        u = pl.load(u_ref, (pl.dslice(0, 1), pl.dslice(t, 1),
-                            slice(None)))[0, 0]      # (bd,)
-        dt = pl.load(dt_ref, (pl.dslice(0, 1), pl.dslice(t, 1),
-                              slice(None)))[0, 0]    # (bd,)
-        Bt = pl.load(b_ref, (pl.dslice(0, 1), pl.dslice(t, 1),
-                             slice(None)))[0, 0]     # (N,)
-        Ct = pl.load(c_ref, (pl.dslice(0, 1), pl.dslice(t, 1),
-                             slice(None)))[0, 0]     # (N,)
+        # t is a traced loop index: a size-1 pl.ds row of each block
+        u = u_ref[0, pl.ds(t, 1), :][0]              # (bd,)
+        dt = dt_ref[0, pl.ds(t, 1), :][0]            # (bd,)
+        Bt = b_ref[0, pl.ds(t, 1), :][0]             # (N,)
+        Ct = c_ref[0, pl.ds(t, 1), :][0]             # (N,)
         a = jnp.exp(dt[:, None] * A)
         h = a * h + (dt * u)[:, None] * Bt[None, :]
         y = jnp.sum(h * Ct[None, :], axis=-1)      # (bd,)
-        pl.store(y_ref, (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-                 y[None, None, :])
+        y_ref[0, pl.ds(t, 1), :] = y[None, :]
         return h
 
     h = jax.lax.fori_loop(0, seq_len, step, h)

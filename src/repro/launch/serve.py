@@ -34,6 +34,8 @@ import time
 import jax
 import numpy as np
 
+from repro.compile.cache import enable_compile_cache
+
 RESNET_ARCHS = ("resnet8", "resnet20")
 
 
@@ -137,8 +139,8 @@ def serve_resnet_traffic(args, cfg, qp, buckets):
             args.arrival_rate, seed=args.seed,
             class_mix={c.name: 1.0 for c in classes}).generate(
                 n=args.requests)
-    n_dev = jax.local_device_count()
-    replicas = min(max(args.replicas, 1), n_dev)
+    # more replicas than devices raises in ReplicaPool, never clamps
+    replicas = max(args.replicas, 1)
     variants = {args.arch: ShardedResNetEngine(
         cfg, qp, batch=args.batch, backend=args.backend, replicas=replicas,
         batch_sizes=buckets, slack_ms=args.slack_ms, tune=args.tune or None)}
@@ -326,6 +328,7 @@ def main():
                          "'analytic' is cost-model-only, 'device' forces a "
                          "fresh two-stage search")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.arch in RESNET_ARCHS:
         serve_resnet(args)
     else:

@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.configs import base as cbase
 from repro.data.synthetic import SyntheticCifar, SyntheticTokens
+from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import make_train_step
 from repro.models import model as M, resnet as R
 from repro.parallel import ctx, sharding as shd
@@ -53,8 +54,7 @@ def train_lm(args):
            else cbase.get_config(args.arch))
     mesh = None
     if args.mesh_model > 1 or args.mesh_data > 1:
-        mesh = jax.make_mesh((args.mesh_data, args.mesh_model),
-                             ("data", "model"))
+        mesh = make_local_mesh(args.mesh_data, args.mesh_model)
     params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
     opt = opt_lib.adamw(lr=args.lr, total_steps=args.steps,
                         int8_state=args.int8_opt)

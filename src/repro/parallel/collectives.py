@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel._compat import shard_map
+from jax import shard_map
 
 from repro.core import quant as Q
 

@@ -45,7 +45,7 @@ def sharded_take(emb, tokens):
     if _MESH is None or "model" not in _MESH.axis_names or \
             emb.shape[1] % _MESH.shape["model"] != 0:
         return jax.numpy.take(emb, tokens, axis=0)
-    from repro.parallel._compat import shard_map
+    from jax import shard_map
     ba = batch_axes()
     import numpy as np
     nb = int(np.prod([_MESH.shape[a] for a in ba])) if ba else 1

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel._compat import shard_map
+from jax import shard_map
 
 
 def partition_stages(costs: Sequence[float], n_stages: int) -> List[int]:

@@ -15,6 +15,15 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with Auto axis types, the one kind the serving and launch
+    meshes use.  ``jax.make_mesh`` builds Explicit axes, which
+    ``with_sharding_constraint`` refuses and on which the host-side bucket
+    slicing of a sharded output has no unambiguous sharding."""
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(jax.sharding.AxisType.Auto,) * mesh.devices.ndim)
+
+
 def axis_size(mesh: Mesh, name) -> int:
     """Product of the named axes' sizes; an axis absent from the mesh counts
     as 1, so the sharding rules degrade gracefully on reduced meshes (e.g. a

@@ -5,11 +5,13 @@ for every task of the lowering plan — the stem kernel and one fused
 residual-block kernel per block — enumerate the tiling knobs that are
 
   1. **divisor-legal**: ``batch_tile | N`` and ``cout_block | Cout`` so the
-     Pallas grid tiles the iteration space exactly;
-  2. **VMEM-legal**: the per-grid-step footprint (input tile floored by the
-     eq. 16 window buffer, filter slice, int32 accumulator, output tile —
-     ``core.dataflow.conv_task_vmem_bytes`` / ``resblock_task_vmem_bytes``)
-     fits the per-core budget, the TPU analogue of the BRAM cap;
+     Pallas grid tiles the iteration space exactly, and a channel block is
+     the full width or a multiple of the chip's 128 lanes (Mosaic refuses
+     any other minor block dim);
+  2. **VMEM-legal**: the per-grid-step footprint, counted in the chip's
+     lane-padded tiles (``core.dataflow.conv_task_vmem_bytes`` /
+     ``resblock_task_vmem_bytes`` / ``chain_task_vmem_bytes``), fits the
+     per-core budget, the TPU analogue of the BRAM cap;
   3. **balance-pruned**: channel blocks below the eq. 12-14 balanced unroll
      (``core.ilp.balanced_och_par``) are dropped — a task tiled below its
      balanced ``och_par`` is the pipeline bottleneck by construction, so
@@ -28,9 +30,10 @@ from typing import Dict, List
 from repro.core import dataflow, ilp
 from repro.tune.config import KernelConfig
 
-# Per-core VMEM budget (v5e-class ~16 MiB, minus headroom for Mosaic's own
-# scratch).  CIFAR-scale tiles are far below this; the cap exists so the
-# enumerator stays legal for larger inputs.
+# Per-core VMEM budget: Mosaic's default scoped-VMEM limit on v5e is 16 MiB;
+# 12 MiB leaves headroom for its own scratch.  The lane-padded models put a
+# ResNet20 32x32x16 stage near 3-4 MiB per image, so this cap sets the
+# batch tile.
 VMEM_BUDGET = 12 * 2**20
 
 
@@ -47,6 +50,8 @@ def stem_space(layer: dataflow.ConvLayer, batch: int, cout_floor: int = 1,
         for cb in divisors(layer.och):
             if cb < floor:
                 continue                      # balance-pruned (eq. 12-14)
+            if cb != layer.och and cb % 128:
+                continue                      # Mosaic: lane blocks of 128
             if dataflow.conv_task_vmem_bytes(layer, bt, cb) > vmem_budget:
                 continue
             out.append(KernelConfig(batch_tile=bt, cout_block=cb))

@@ -2,6 +2,9 @@
 registry, and the compiled-executable contract (bit-exactness, buckets,
 padding, zero retracing)."""
 import dataclasses
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -176,6 +179,22 @@ def test_float_backend_tracks_integer_backend(qp8, images):
                                atol=1e-4)
 
 
+@pytest.mark.parametrize("batch", [1, 5])
+def test_int_head_is_exact_per_row(qp8, batch):
+    """The pool + classifier tail is integer up to one float scale and the
+    float bias, so each logit equals an int64 host computation bit for bit,
+    whatever the batch shape (a float dot on TPU is not shape-invariant)."""
+    from repro.compile.backends import _int_head
+    fc = C.QResNetParams.from_dict(qp8).fc
+    h = np.random.default_rng(batch).integers(0, 256, (batch, 8, 8, 64),
+                                              dtype=np.uint8)
+    got = np.asarray(jax.jit(_int_head, static_argnums=2)(h, fc, R.A_SPEC))
+    acc = h.astype(np.int64).sum(axis=(1, 2)) @ np.asarray(fc.wq, np.int64)
+    scale = np.float32(R.A_SPEC.scale * fc.w_spec.scale / 64)
+    want = acc.astype(np.float32) * scale + np.asarray(fc.b, np.float32)
+    np.testing.assert_array_equal(got, want)
+
+
 def test_bucket_selection_padding_and_chunking(qp8, images):
     cfg = R.RESNET8
     cm = C.compile_model(cfg, qp8, backend="lax-int", batch_sizes=(2, 4))
@@ -222,3 +241,42 @@ def test_compile_model_rejects_bad_buckets(qp8):
         cm.executable(3)
     with pytest.raises(ValueError, match="empty"):
         cm(jnp.zeros((0, 32, 32, 3)))
+
+
+_CACHE_PROBE = """
+import jax, jax.numpy as jnp
+from repro.compile.cache import enable_compile_cache
+print(enable_compile_cache())
+print(jax.config.jax_compilation_cache_dir)
+if {compile}:
+    jax.jit(lambda x: x * 3 + 1).lower(jnp.ones(8)).compile()
+"""
+
+
+def _cache_probe(env_dir, compile_):
+    """Run ``enable_compile_cache`` in a fresh process (it changes global
+    JAX config); returns its two printed directories."""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    r = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE.format(compile=compile_)],
+        capture_output=True, text=True, env=env, timeout=300)
+    assert r.returncode == 0, r.stderr[-3000:]
+    return r.stdout.split()
+
+
+def test_compile_cache_uses_env_dir_when_set(tmp_path):
+    d = str(tmp_path / "cache")
+    assert _cache_probe(d, compile_=True) == [d, d]
+    assert os.listdir(d), "nothing was cached in $JAX_COMPILATION_CACHE_DIR"
+
+
+def test_compile_cache_defaults_to_the_checkout():
+    from repro.compile.cache import CHECKOUT_CACHE_DIR
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert CHECKOUT_CACHE_DIR == os.path.join(root, ".jax_cache")
+    assert _cache_probe(None, compile_=False) == [CHECKOUT_CACHE_DIR] * 2

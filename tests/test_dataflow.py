@@ -110,13 +110,15 @@ def test_chain_vmem_monotone_in_links_and_tile():
     assert df.chain_task_vmem_bytes(shapes, 4) > \
         df.chain_task_vmem_bytes(shapes, 1)
     # fusing the stem trades the 16-channel boundary input tile for the raw
-    # 3-channel image plus the stem filter+bias; the stem working set is
-    # dominated by the first block's, so the net delta is exactly that swap
+    # 3-channel image — the same lane-padded VMEM tile — and adds the stem
+    # filter+bias; the stem working set is dominated by the first block's,
+    # so the net delta is exactly the pinned stem filter
     with_stem = df.chain_task_vmem_bytes(shapes, 1, stem_och=16)
     without = df.chain_task_vmem_bytes(shapes, 1)
-    stem_wts = 9 * 3 * 16 + 16 * 4
-    in_tile_saved = 34 * 34 * (16 - 3)
-    assert with_stem - without == stem_wts - in_tile_saved
+    assert df.tiled_bytes((34, 34, 3), 1) == df.tiled_bytes((34, 34, 16), 1)
+    stem_wts = 2 * (df.tiled_bytes((3, 3, 3, 16), 1)
+                    + df.tiled_bytes((16,), 4))
+    assert with_stem - without == stem_wts
 
 
 def test_over_budget_chain_rejected_by_tune_space():

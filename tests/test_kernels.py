@@ -330,12 +330,13 @@ def test_block_chain_equals_per_block_kernels():
 
 
 def test_f32_emulation_bound_is_enforced():
-    """The interpret-mode fast path runs tap dots in float32 ONLY below the
-    2^24 exactness bound; a hypothetical wider-than-517-channel link must
+    """The interpret-mode fast path runs tap dots in float32 ONLY within the
+    2^24 exactness bound; a hypothetical wider-than-1024-channel link must
     fall back to integer dots (checked structurally, not numerically)."""
     from repro.kernels.megakernel.megakernel import F32_EXACT_ROWS, _dot_i32
-    assert F32_EXACT_ROWS * 127 * 255 < 2 ** 24
-    assert (F32_EXACT_ROWS + 1) * 127 * 255 >= 2 ** 24
+    # offset-s8 operands: every product is at most 128 * 128 in magnitude
+    assert F32_EXACT_ROWS * 128 * 128 <= 2 ** 24
+    assert (F32_EXACT_ROWS + 1) * 128 * 128 > 2 ** 24
     wide = jnp.ones((2, F32_EXACT_ROWS + 1), jnp.uint8)
     wm = jnp.ones((F32_EXACT_ROWS + 1, 4), jnp.int8)
     assert _dot_i32(wide, wm, fast_emul=True).dtype == jnp.int32

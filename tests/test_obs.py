@@ -598,7 +598,7 @@ def test_obs_off_serving_is_bit_identical():
 @pytest.mark.slow
 def test_overhead_obs_bench_under_three_percent():
     """PR acceptance: the overhead_obs benchmark measures <3% enabled
-    overhead on the e2e_pallas workload (best-of-reps interleaved timing;
+    overhead on the e2e_pallas workload (the median of interleaved on/off pairs;
     retried to ride out host noise — the enabled path only adds counter
     increments, so a persistent >=3% reading is a real regression)."""
     from benchmarks import run as bench

@@ -138,11 +138,12 @@ def test_compressed_grad_allreduce():
     out = run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
-        from repro.parallel._compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.parallel.collectives import (compressed_psum_grads,
                                                 init_error_state)
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = jax.make_mesh((4,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         key = jax.random.PRNGKey(0)
         g = jax.random.normal(key, (4, 8, 256))  # per-device grads
 

@@ -128,6 +128,20 @@ def test_sharded_engine_validates_shape_and_buckets(qp8):
                             replicas=jax.local_device_count() + 7)
 
 
+def test_launch_serve_rejects_more_replicas_than_devices(qp8):
+    """``launch.serve --replicas`` above the device count is an error, never
+    a silent clamp to the devices there are."""
+    import argparse
+    from repro.launch.serve import serve_resnet_traffic
+
+    args = argparse.Namespace(
+        arch="resnet8", slo_classes="gold:50:0", trace="", requests=4,
+        arrival_rate=100.0, seed=0, replicas=jax.local_device_count() + 1,
+        batch=2, backend="lax-int", slack_ms=1.0, tune="", degrade_arch="")
+    with pytest.raises(ValueError, match="devices are available"):
+        serve_resnet_traffic(args, R.RESNET8, qp8, (2,))
+
+
 def test_fake_clock_engine_is_deterministic(qp8, images):
     """With an injected FakeClock, the engine's scheduling timeline is fully
     simulated: queue waits come out as exact simulated values."""

@@ -125,6 +125,8 @@ def test_model_space_structure_and_balance_pruning():
     for c in spaces["stem"]:
         assert c.cout_block >= floor     # eq. 12-14 pruning
         assert 16 % c.cout_block == 0 and 4 % c.batch_tile == 0
+        # Mosaic takes a lane block of the full width or of 128s only
+        assert c.cout_block in (0, 16) or c.cout_block % 128 == 0
     for k in ("block0", "block1", "block2"):
         for c in spaces[k]:
             assert c.cout_block == 0     # fusion-illegal knob never enumerated
