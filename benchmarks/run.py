@@ -629,7 +629,8 @@ def overhead_obs():
     """The observability tax on the e2e_pallas workload: one compiled
     ResNet8 executable interleave-timed (host drift cancels) with a
     ``repro.obs`` session installed vs removed around each call.  On the
-    direct compiled path the enabled cost is the counter increments in
+    direct compiled path the enabled cost is the counter increments and the
+    ``model.put``/``model.launch`` host spans in
     ``CompiledModel._run_batched``; the acceptance (<3% enabled overhead,
     slow-marked in tests/test_obs.py; exactly zero calls when disabled,
     enforced by the poisoned-observer test) keeps instrumentation honest.
@@ -668,8 +669,9 @@ def overhead_obs():
             obsrt.install(None)
     finally:
         obsrt.install(prior)
-    # the work is identical modulo two counter incs.  Every number of the
-    # row comes from the median pair (upper median) by on/off time ratio:
+    # the work is identical modulo two counter incs and two host spans.
+    # Every number of the row comes from the median pair (upper median) by
+    # on/off time ratio:
     # the two calls of a pair share the host's load, and the median drops
     # the pairs a scheduler spike split (best-of per mode, which pairs the
     # fastest call of each mode from different moments, read up to +17% on

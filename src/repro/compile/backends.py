@@ -391,11 +391,12 @@ class PallasBackend:
         stem_out, block_outs = activation_out_specs(params, A_SPEC)
 
         def forward(images):
-            xq = Q.quantize(images, params.stem.x_spec)
+            with jax.named_scope("input_quantize"):
+                xq = Q.quantize(images, params.stem.x_spec)
             st = params.stem
             h = conv_stem_op(xq, st.wq, st.bq,
                              shift=stem_out.exp - st.product_exp,
-                             config=plan.stem.config)
+                             config=plan.stem.config, name="stem")
             for task in plan.blocks:
                 blk = params.blocks[task.index]
                 sh = blk.shifts_for(block_outs[task.index].exp)
@@ -406,7 +407,9 @@ class PallasBackend:
                 h = resblock_fused_op(
                     h, blk.conv0.wq, blk.conv0.bq.astype(jnp.int32),
                     blk.conv1.wq, blk.conv1.bq.astype(jnp.int32),
-                    wd, bd, stride=task.stride, config=task.config, **sh)
+                    wd, bd, stride=task.stride, config=task.config,
+                    name=lowering.kernel_name(task, cfg.blocks_per_stage),
+                    **sh)
             return _int_head(h, params.fc,
                              block_outs[-1] if block_outs else stem_out)
 
@@ -471,6 +474,7 @@ class PallasStreamBackend:
         stem_out, block_outs = activation_out_specs(params, A_SPEC)
         st = params.stem
         stem_shift = stem_out.exp - st.product_exp
+        bps = cfg.blocks_per_stage
 
         # static per-chain schedule: (operand pytree, ChainBlockSpec tuple)
         lowered = []
@@ -489,11 +493,12 @@ class PallasStreamBackend:
             lowered.append((chain, tuple(ops), tuple(specs)))
 
         def forward(images):
-            h = Q.quantize(images, st.x_spec)
+            with jax.named_scope("input_quantize"):
+                h = Q.quantize(images, st.x_spec)
             if not chains or chains[0].stem is None:
                 # stem not fused into the first chain: per-kernel fallback
                 h = conv_stem_op(h, st.wq, st.bq, shift=stem_shift,
-                                 config=plan.stem.config)
+                                 config=plan.stem.config, name="stem")
             for chain, ops, specs in lowered:
                 if len(specs) == 1 and chain.stem is None:
                     # singleton chain: the megakernel would add nothing —
@@ -506,14 +511,16 @@ class PallasStreamBackend:
                     h = resblock_fused_op(
                         h, blk.conv0.wq, blk.conv0.bq.astype(jnp.int32),
                         blk.conv1.wq, blk.conv1.bq.astype(jnp.int32),
-                        wd, bd, stride=task.stride, config=task.config, **sh)
+                        wd, bd, stride=task.stride, config=task.config,
+                        name=lowering.kernel_name(task, bps), **sh)
                     continue
                 stem = (st.wq, st.bq.astype(jnp.int32)) \
                     if chain.stem is not None else None
                 h = block_chain_op(
                     h, ops, specs=specs, stem=stem,
                     stem_shift=stem_shift if chain.stem is not None else None,
-                    config=chain_config(chain, images.shape[0]))
+                    config=chain_config(chain, images.shape[0]),
+                    name=lowering.kernel_name(chain, bps))
             return _int_head(h, params.fc,
                              block_outs[-1] if block_outs else stem_out)
 

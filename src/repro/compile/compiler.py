@@ -42,6 +42,7 @@ from repro.compile import lowering
 from repro.compile.backends import Backend, get_backend
 from repro.compile.params import QResNetParams, ensure_typed
 from repro.obs import runtime as _obs
+from repro.obs.trace import host_ns
 
 
 def _donate_argnums():
@@ -184,7 +185,7 @@ class CompiledModel:
         """``__call__`` pinned to one device: the shared batching discipline
         plus a device_put.  Bit-exact with the default path — placement
         never changes the arithmetic."""
-        def rb(imgs, bucket, padded):
+        def place(imgs, padded):
             placed = jax.device_put(imgs, device)
             if self._donate and not padded:
                 # same donation guard as __call__.  The copy must be
@@ -192,9 +193,11 @@ class CompiledModel:
                 # `device` returns a NEW object aliasing the SAME buffer, so
                 # no identity/no-op check can detect the caller's buffer
                 placed = jnp.array(placed, copy=True)
-            return self.device_executable(bucket, device)(placed)
+            return placed
 
-        return self._run_batched(images, self.batch_sizes, rb)
+        return self._run_batched(
+            images, self.batch_sizes, place,
+            lambda bucket: self.device_executable(bucket, device))
 
     def shard_executable(self, mesh, batch: int, axis: str = "data"):
         """One SPMD executable over ``mesh``: the batch dim sharded over
@@ -265,11 +268,12 @@ class CompiledModel:
                 f"no compiled bucket in {self.batch_sizes} divides over "
                 f"mesh axis {axis!r} (size {n_dev})")
 
-        def rb(imgs, bucket, padded):
-            imgs = jax.device_put(imgs, NamedSharding(mesh, P(axis)))
-            return self.shard_executable(mesh, bucket, axis)(imgs)
+        def place(imgs, padded):
+            return jax.device_put(imgs, NamedSharding(mesh, P(axis)))
 
-        return self._run_batched(images, fits, rb)
+        return self._run_batched(
+            images, fits, place,
+            lambda bucket: self.shard_executable(mesh, bucket, axis))
 
     # -- dispatch -----------------------------------------------------------
 
@@ -281,19 +285,23 @@ class CompiledModel:
                 return b
         return self.batch_sizes[-1]
 
-    def _run_batched(self, images, buckets, run_bucket) -> jnp.ndarray:
+    def _run_batched(self, images, buckets, place, executable) -> jnp.ndarray:
         """THE one home for the serving batching discipline, shared by
         ``__call__``/``run_placed``/``run_sharded``: select the smallest
         bucket >= n from ``buckets``, zero-pad up to it, chunk batches
         beyond the largest bucket, slice the pad rows off the logits.
-        ``run_bucket(imgs, bucket, padded)`` executes one full bucket."""
+        ``place(imgs, padded)`` puts one full bucket where
+        ``executable(bucket)`` runs it."""
+        ob = _obs.active()
+        t0 = host_ns() if ob is not None else 0
         images = jnp.asarray(images, self._in_dtype)
         n = images.shape[0]
         if n == 0:
             raise ValueError("empty batch")
         cap = buckets[-1]
         if n > cap:
-            outs = [self._run_batched(images[i:i + cap], buckets, run_bucket)
+            outs = [self._run_batched(images[i:i + cap], buckets, place,
+                                      executable)
                     for i in range(0, n, cap)]
             return jnp.concatenate(outs, axis=0)
         bucket = next(b for b in buckets if b >= n)
@@ -301,11 +309,15 @@ class CompiledModel:
             images = jnp.concatenate(
                 [images, jnp.zeros((bucket - n,) + images.shape[1:],
                                    images.dtype)], axis=0)
-        ob = _obs.active()
+        placed = place(images, n < bucket)
+        t1 = host_ns() if ob is not None else 0
+        out = executable(bucket)(placed)[:n]
         if ob is not None:
-            # counters only on the hot path — executions dispatch async, so
-            # a wall-timed span here would measure dispatch, not compute
-            # (the scheduler's per-request compute span covers that)
+            # host time only: the launch returns before the device is done
+            # (the engines' engine.wait span holds the device's share); an
+            # engine claims both spans for its batch (Trace.host_claim)
+            ob.trace.host_span("model.put", t0, t1)
+            ob.trace.host_span("model.launch", t1, host_ns())
             ob.metrics.counter(
                 "model_runs_total", "bucket executions dispatched").inc(
                     bucket=str(bucket), backend=self.backend.name)
@@ -315,17 +327,18 @@ class CompiledModel:
                     "zero-pad rows added by bucket rounding").inc(
                         bucket - n, bucket=str(bucket),
                         backend=self.backend.name)
-        return run_bucket(images, bucket, n < bucket)[:n]
+        return out
 
     def __call__(self, images) -> jnp.ndarray:
-        def rb(imgs, bucket, padded):
+        def place(imgs, padded):
             if self._donate and not padded:
                 # the executable donates its input buffer; never hand it
                 # the caller's array (the padded branch made a fresh one)
-                imgs = jnp.array(imgs, copy=True)
-            return self.executable(bucket)(imgs)
+                return jnp.array(imgs, copy=True)
+            return imgs
 
-        return self._run_batched(images, self.batch_sizes, rb)
+        return self._run_batched(images, self.batch_sizes, place,
+                                 self.executable)
 
     # -- introspection ------------------------------------------------------
 

@@ -475,6 +475,22 @@ def plan_lm(g: G.Graph, params=None) -> LMPlan:
                   seq_len=state.embed.attrs["seq_len"])
 
 
+def kernel_name(task, blocks_per_stage: int) -> str:
+    """The device-op name of a task's kernel: its place in the network, so
+    a profile finds it across compiles and refactors.  ``stem``;
+    ``s<stage>b<block>`` for a residual block (stages from 1, blocks from
+    0), with ``_ds`` when its 1x1 downsample is fused; ``[stem_]<first>``
+    or ``[stem_]<first>_to_<last>`` for a chain."""
+    if isinstance(task, StemTask):
+        return "stem"
+    if isinstance(task, ChainTask):
+        names = [kernel_name(t, blocks_per_stage) for t in task.blocks]
+        name = names[0] if len(names) == 1 else f"{names[0]}_to_{names[-1]}"
+        return name if task.stem is None else f"stem_{name}"
+    stage, block = divmod(task.index, blocks_per_stage)
+    return f"s{stage + 1}b{block}" + ("_ds" if task.has_ds else "")
+
+
 def plan_chains(plan: LoweringPlan, cfg, cuts=None, fuse_stem: bool = True,
                 vmem_budget: Optional[int] = None) -> List[ChainTask]:
     """Partition the plan's block sequence into streaming chains — the front

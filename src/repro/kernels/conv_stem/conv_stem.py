@@ -36,11 +36,12 @@ def _kernel(x_ref, w_ref, b_ref, o_ref, *, shift):
     o_ref[...] = requant(acc, shift).astype(jnp.uint8)
 
 
-def conv_stem(x, w, b, *, shift, batch_tile=1, cout_block=0, interpret=False):
+def conv_stem(x, w, b, *, shift, batch_tile=1, cout_block=0, interpret=False,
+              name=None):
     """x: (N,H,W,Cin) uint8 unpadded; w: (3,3,Cin,Cout) int8; b: (Cout,) int.
     Returns (N,H,W,Cout) uint8 post-ReLU activations (SAME padding).
     ``batch_tile`` must divide N and ``cout_block`` must divide Cout
-    (0 = maximal)."""
+    (0 = maximal).  ``name`` names the kernel's device op."""
     N, H, W, Cin = x.shape
     fh, fw, _, Cout = w.shape
     bt = N if batch_tile == 0 else batch_tile
@@ -60,4 +61,5 @@ def conv_stem(x, w, b, *, shift, batch_tile=1, cout_block=0, interpret=False):
         out_specs=pl.BlockSpec((bt, H, W, cb), lambda n, c: (n, 0, 0, c)),
         out_shape=jax.ShapeDtypeStruct((N, H, W, Cout), jnp.uint8),
         interpret=interpret,
+        name=name,
     )(xp, w, offset_bias(b, w))

@@ -134,12 +134,14 @@ def _kernel(*refs, specs: Tuple[ChainBlockSpec, ...], stem_shift, n_in,
 
 def block_chain(x, blocks, *, specs: Tuple[ChainBlockSpec, ...],
                 stem=None, stem_shift: Optional[int] = None,
-                batch_tile: int = 1, interpret: bool = False):
+                batch_tile: int = 1, interpret: bool = False,
+                name: Optional[str] = None):
     """x: (N,H,W,Cin) uint8 unpadded, H and W even — the image batch when
     ``stem`` is fused, else the previous kernel's activation.  ``blocks``:
     one (w0,b0,w1,b1[,wd,bd]) tuple per chain link; ``stem``: optional
     (w, b) fused at the chain head.  Returns the last block's (N,oh,ow,Cout)
-    uint8 output; every intermediate activation lives and dies in VMEM."""
+    uint8 output; every intermediate activation lives and dies in VMEM.
+    ``name`` names the kernel's device op."""
     assert len(blocks) == len(specs) and specs, (len(blocks), len(specs))
     N, oh, ow, _ = x.shape
     bt = N if batch_tile == 0 else batch_tile
@@ -190,4 +192,5 @@ def block_chain(x, blocks, *, specs: Tuple[ChainBlockSpec, ...],
         out_shape=jax.ShapeDtypeStruct((N, oh, ow, cout), jnp.uint8),
         scratch_shapes=[pltpu.VMEM(s, jnp.int32) for s in stage_shapes],
         interpret=interpret,
+        name=name,
     )(*operands)

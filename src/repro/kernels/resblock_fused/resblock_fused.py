@@ -58,13 +58,14 @@ def _kernel(x_ref, w0_ref, b0_ref, w1_ref, b1_ref, *rest, stride, shift0,
 
 def resblock_fused(x, w0, b0, w1, b1, wd=None, bd=None, *, stride=1,
                    shift0, shift1, skip_shift=0, batch_tile=1,
-                   interpret=False):
+                   interpret=False, name=None):
     """x: (N,H,W,Cin) uint8 unpadded, H and W even; w0: (3,3,Cin,Cout) int8;
     w1: (3,3,Cout,Cout) int8; b0/b1: (Cout,) int; wd: (1,1,Cin,Cout) int8 +
     bd: (Cout,) int for the fused downsample skip (None for identity skip).
     shift0/shift1: pow2 requant shifts (positive = right shift); skip_shift:
     signed product-domain alignment shift.  ``batch_tile`` images per grid
-    step (0 = whole batch, must divide N).  Returns (N,H/s,W/s,Cout) uint8."""
+    step (0 = whole batch, must divide N).  ``name`` names the kernel's
+    device op.  Returns (N,H/s,W/s,Cout) uint8."""
     N, H, W, Cin = x.shape
     Cout = w0.shape[-1]
     has_ds = wd is not None
@@ -95,4 +96,5 @@ def resblock_fused(x, w0, b0, w1, b1, wd=None, bd=None, *, stride=1,
         out_shape=jax.ShapeDtypeStruct((N, oh, ow, Cout), jnp.uint8),
         scratch_shapes=stage,
         interpret=interpret,
+        name=name,
     )(*operands)

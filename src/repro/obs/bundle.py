@@ -4,7 +4,7 @@ A bundle is a directory (named ``bundle_{seq:03d}_{reason}`` —
 deterministic, no wall-clock in the name) holding everything needed to
 diagnose an incident offline:
 
-* ``manifest.json`` — reason, injected-clock timestamp, alert count,
+* ``manifest.json`` — reason, timestamp, alert count,
   active config/bucket census, recorder stats, file list.
 * ``trace.json``    — Chrome ``trace_event`` JSON (the flight-recorder
   ring when one is attached, else the session's full trace).
@@ -16,6 +16,10 @@ diagnose an incident offline:
 ``python -m repro.obs`` report CLI uses, so the formats cannot drift from
 what the tooling accepts; :func:`assemble_bundle` builds a bundle from
 already-exported artifacts (the offline ``dump`` subcommand).
+
+Every timestamp in a bundle is on the session trace's exported base
+(``Trace.export_t``): the injected clock's seconds in a simulation,
+``time.time_ns`` seconds in a live session.
 """
 from __future__ import annotations
 
@@ -64,7 +68,7 @@ def write_bundle(dir_path: str, ob, reason: str, now: float, seq: int = 0,
 
     with open(os.path.join(bdir, _ALERTS), "w") as f:
         for a in alerts:
-            f.write(a.to_json() + "\n")
+            f.write(a.to_json(ob.trace.export_t) + "\n")
 
     delta_lines = recorder.delta_lines() if recorder is not None else []
     with open(os.path.join(bdir, _DELTAS), "w") as f:
@@ -74,7 +78,7 @@ def write_bundle(dir_path: str, ob, reason: str, now: float, seq: int = 0,
     manifest = dict(
         schema=BUNDLE_SCHEMA,
         reason=reason,
-        t=now,
+        t=ob.trace.export_t(now),
         seq=seq,
         alerts=len(alerts),
         census=census or {},

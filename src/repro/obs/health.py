@@ -31,7 +31,8 @@ Rules
 Every firing is a typed :class:`Alert`, recorded three ways at once: an
 ``alert`` trace instant, a ``health_alerts_total{rule=...}`` counter, and
 an entry in the monitor's alert log (``alert_log_jsonl()`` is byte-stable
-across same-seed runs — sorted keys, injected-clock timestamps only).
+across same-seed runs — sorted keys, injected-clock timestamps only; a
+live session's log is on its trace's exported base, as its instants are).
 
 Rules are *edge-triggered with hysteresis*: a rule fires once on the
 rising edge and re-arms only after its condition has cleared, so a
@@ -83,12 +84,15 @@ class Alert:
     message: str
     context: Tuple[Tuple[str, Any], ...] = ()
 
-    def to_dict(self) -> dict:
-        return dict(rule=self.rule, severity=self.severity, t=self.t,
+    def to_dict(self, export_t=None) -> dict:
+        """``export_t`` (a session's ``Trace.export_t``) puts ``t`` on the
+        exported time base."""
+        t = self.t if export_t is None else export_t(self.t)
+        return dict(rule=self.rule, severity=self.severity, t=t,
                     message=self.message, context=dict(self.context))
 
-    def to_json(self) -> str:
-        return json.dumps(self.to_dict(), sort_keys=True)
+    def to_json(self, export_t=None) -> str:
+        return json.dumps(self.to_dict(export_t), sort_keys=True)
 
 
 def alert_log_path(metrics_out: str) -> str:
@@ -548,7 +552,8 @@ class HealthMonitor:
     def alert_log_jsonl(self) -> str:
         """One canonical JSON object per alert, firing order — the
         byte-stable artifact the determinism tests compare."""
-        return "".join(a.to_json() + "\n" for a in self.alerts)
+        return "".join(a.to_json(self.ob.trace.export_t) + "\n"
+                       for a in self.alerts)
 
     def write_alert_log(self, path: str) -> None:
         with open(path, "w") as f:

@@ -8,7 +8,9 @@ events in a ring; :meth:`record_metrics` (called from the health
 monitor's tick) stores *changed-keys-only* metric deltas in a second
 ring.  Both rings live on the injected clock — nothing here reads wall
 time — and eviction is pure ``deque(maxlen=...)``, so overhead is a
-constant append per event.
+constant append per event.  The exports put both rings on the attached
+trace's exported time base (``Trace.export_t``), so a live session's
+bundle lines up with its trace.
 
 On alert (or drain-with-missed-deadlines, or ``python -m repro.obs
 dump``) the rings are frozen into a debug bundle — see
@@ -17,9 +19,9 @@ dump``) the rings are frozen into a debug bundle — see
 from __future__ import annotations
 
 import collections
-from typing import Any, Deque, Dict, List, Tuple
+from typing import Any, Deque, Dict, List, Optional, Tuple
 
-from repro.obs.trace import Trace, TraceEvent
+from repro.obs.trace import Trace, TraceEvent, chrome_json
 
 __all__ = ["FlightRecorder", "flatten_snapshot"]
 
@@ -56,10 +58,15 @@ class FlightRecorder:
         self.dropped_events = 0
         self.seen_events = 0
         self._last_flat: Dict[str, float] = {}
+        self.trace: Optional[Trace] = None
+
+    def _export_t(self, t: float) -> float:
+        return self.trace.export_t(t) if self.trace is not None else t
 
     # -- trace side ---------------------------------------------------------
 
     def attach(self, trace: Trace) -> None:
+        self.trace = trace
         trace.listeners.append(self.on_event)
 
     def on_event(self, e: TraceEvent) -> None:
@@ -86,12 +93,14 @@ class FlightRecorder:
     def chrome(self) -> dict:
         """Chrome ``trace_event`` JSON over the ring contents only (same
         format as ``Trace.chrome`` — Perfetto-loadable)."""
-        snap = Trace()
-        snap.events = list(self.events)
-        return snap.chrome()
+        events = list(self.events)
+        if self.trace is not None:
+            events = self.trace.exported(events)
+        return chrome_json(events)
 
     def delta_lines(self) -> List[dict]:
-        return [dict(t=t, changed=dict(sorted(changed.items())))
+        return [dict(t=self._export_t(t),
+                     changed=dict(sorted(changed.items())))
                 for t, changed in self.deltas]
 
     def summary(self) -> dict:

@@ -18,15 +18,22 @@ so a virtual-time simulation yields a deterministic event log);
 ``disable()`` removes it; ``instrumented()`` is the context-manager form.
 Only one session is active at a time — the last ``instrument()`` wins,
 which is the right semantics for a CLI process.
+
+While a live session (no injected clock) is installed, a ``gc.callbacks``
+hook records every garbage collection as a ``gc`` host span with its
+generation, so a stall in the trace can be put down to a collection.  The
+hook is registered only then: with no session, or a ``FakeClock`` one,
+collections cost nothing extra and simulations stay deterministic.
 """
 from __future__ import annotations
 
 import contextlib
+import gc
 import time
 from typing import List, Optional
 
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import Trace
+from repro.obs.trace import Trace, host_ns
 
 __all__ = [
     "Observability", "active", "instrument", "install", "disable",
@@ -57,6 +64,7 @@ class Observability:
 
 
 _ACTIVE: Optional[Observability] = None
+_GC_START = [0]                   # host_ns at the running collection's start
 
 
 def active() -> Optional[Observability]:
@@ -64,11 +72,15 @@ def active() -> Optional[Observability]:
     return _ACTIVE
 
 
-def instrument(clock=None) -> Observability:
-    """Install (and return) a fresh observability session."""
-    global _ACTIVE
-    _ACTIVE = Observability(clock=clock)
-    return _ACTIVE
+def _on_gc(phase: str, info: dict) -> None:
+    if phase == "start":
+        _GC_START[0] = host_ns()
+        return
+    ob = _ACTIVE
+    # a session re-bound to a FakeClock after install (set_clock) stays
+    # deterministic: no wall-timed collections in its log
+    if ob is not None and ob.clock is None:
+        ob.trace.host_span("gc", _GC_START[0], host_ns(), info["generation"])
 
 
 def install(ob: Optional[Observability]) -> Optional[Observability]:
@@ -78,13 +90,24 @@ def install(ob: Optional[Observability]) -> Optional[Observability]:
     active before they borrowed the switch."""
     global _ACTIVE
     _ACTIVE = ob
+    hooked = _on_gc in gc.callbacks
+    live = ob is not None and ob.clock is None
+    if live and not hooked:
+        gc.callbacks.append(_on_gc)
+    elif hooked and not live:
+        gc.callbacks.remove(_on_gc)
     return ob
+
+
+def instrument(clock=None) -> Observability:
+    """Install (and return) a fresh observability session."""
+    return install(Observability(clock=clock))
 
 
 def disable() -> Optional[Observability]:
     """Uninstall the session; returns it so callers can still export."""
-    global _ACTIVE
-    ob, _ACTIVE = _ACTIVE, None
+    ob = _ACTIVE
+    install(None)
     return ob
 
 

@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.models import model as M
 from repro.obs import runtime as _obs
+from repro.obs.trace import host_ns
 from repro.serve import sched as S
 
 # backends whose logits must agree BITWISE with each other: a nonzero A/B
@@ -212,6 +213,7 @@ class ResNetEngine:
         self.ab_stats = {name: [] for name in self.shadows}
         self.queue: List[ImageRequest] = []
         self.served = 0
+        self.ticks = 0                    # batches served (the batch id)
 
     def submit(self, req: ImageRequest):
         """Enqueue one request (shape-validated at admission)."""
@@ -219,18 +221,33 @@ class ResNetEngine:
         self.queue.append(req)
 
     def tick(self) -> bool:
-        """Serve one batch; returns False when the queue is empty."""
+        """Serve one batch; returns False when the queue is empty.
+
+        Under a live obs session the tick records its host spans, all
+        carrying the tick's number: ``engine.tick`` around ``engine.gather``
+        (queue slice + stack), the model's ``model.put``/``model.launch``,
+        ``engine.wait`` (the device), ``engine.fetch`` (readback) and
+        ``engine.fanout`` (per-request results)."""
         if not self.queue:
             return False
+        ob = _obs.active()
+        t0 = host_ns() if ob is not None else 0
         reqs = self.queue[:self.batch]
         del self.queue[:len(reqs)]
         dtype = _input_contract(self.cfg)[1]
         imgs = np.stack([np.asarray(r.image, dtype) for r in reqs])
-        logits = np.asarray(self.model(imgs))
+        if ob is not None:
+            t_gather = host_ns()
+            mark = ob.trace.host_mark()
+        out = self.model(imgs)
+        t_launched = host_ns() if ob is not None else 0
+        jax.block_until_ready(out)
+        t_ready = host_ns() if ob is not None else 0
+        logits = np.asarray(out)
+        t_fetched = host_ns() if ob is not None else 0
         for name, shadow in self.shadows.items():
             dev = np.max(np.abs(np.asarray(shadow(imgs)) - logits))
             self.ab_stats[name].append(float(dev))
-            ob = _obs.active()
             if ob is not None:
                 ob.metrics.counter(
                     "ab_checks_total", "A/B shadow replays").inc(shadow=name)
@@ -244,11 +261,21 @@ class ResNetEngine:
                         "ab_mismatch_total",
                         "integer shadow disagreed bitwise with primary").inc(
                             shadow=name)
+        t_fanout = host_ns() if ob is not None else 0
         for i, r in enumerate(reqs):
             r.logits = logits[i]
             r.label = int(np.argmax(logits[i]))
             r.done = True
         self.served += len(reqs)
+        if ob is not None:
+            t1, tr, bid = host_ns(), ob.trace, self.ticks
+            tr.host_claim(mark, bid)          # the model's put and launch
+            tr.host_span("engine.tick", t0, t1, bid)
+            tr.host_span("engine.gather", t0, t_gather, bid)
+            tr.host_span("engine.wait", t_launched, t_ready, bid)
+            tr.host_span("engine.fetch", t_ready, t_fetched, bid)
+            tr.host_span("engine.fanout", t_fanout, t1, bid)
+        self.ticks += 1
         return True
 
     def run(self, max_ticks: int = 10_000) -> int:
@@ -358,16 +385,32 @@ class ShardedResNetEngine:
         return bool(dispatched or harvested)
 
     def _dispatch_due(self) -> int:
+        """Dispatch every due micro-batch.  Under a live obs session each
+        records ``engine.dispatch`` around ``sched.poll``, ``engine.gather``
+        and the model's ``model.put``/``model.launch``, all carrying the
+        dispatch's ``seq``."""
         n = 0
+        ob = _obs.active()
         while True:
+            t0 = host_ns() if ob is not None else 0
             d = self.sched.poll()
             if d is None:
                 break
+            t_poll = host_ns() if ob is not None else 0
             dtype = _input_contract(self.cfg)[1]
             imgs = np.stack([np.asarray(r.payload.image, dtype)
                              for r in d.requests])
+            if ob is not None:
+                t_gather = host_ns()
+                mark = ob.trace.host_mark()
             out = self.pool.run(d.replica.index, imgs)   # async dispatch
             self._in_flight.append((d, out))
+            if ob is not None:
+                tr = ob.trace
+                tr.host_claim(mark, d.seq)    # the model's put and launch
+                tr.host_span("engine.dispatch", t0, host_ns(), d.seq)
+                tr.host_span("sched.poll", t0, t_poll, d.seq)
+                tr.host_span("engine.gather", t_poll, t_gather, d.seq)
             n += 1
         return n
 
@@ -389,17 +432,25 @@ class ShardedResNetEngine:
         nothing at all was ready, wait on the oldest so the caller always
         makes progress.  Returns between harvests as soon as the remainder
         is still computing — the drive loops interleave ``_dispatch_due``
-        so idle replicas never wait head-of-line on a slow one."""
+        so idle replicas never wait head-of-line on a slow one.  Under a
+        live obs session each harvested dispatch records
+        ``engine.harvest`` around ``engine.wait``, ``engine.fetch``,
+        ``sched.complete`` and ``engine.fanout``, carrying its ``seq``."""
         n = 0
+        ob = _obs.active()
         while self._in_flight:
+            t0 = host_ns() if ob is not None else 0
             i = self._next_ready_index()
             if i is None:
                 if not block or n > 0:
                     break         # let the caller dispatch more work first
                 i = 0             # nothing ready anywhere: wait on the oldest
             d, out = self._in_flight[i]
+            t_wait = host_ns() if ob is not None else 0
             try:
-                logits = np.asarray(jax.block_until_ready(out))
+                jax.block_until_ready(out)
+                t_ready = host_ns() if ob is not None else 0
+                logits = np.asarray(out)
             except Exception:
                 # a dispatch whose device execution errored must not jam the
                 # head of the line or leak in-flight slots: evict it, release
@@ -408,13 +459,22 @@ class ShardedResNetEngine:
                 self._in_flight.pop(i)
                 self.sched.complete(d, failed=True)
                 raise
+            t_fetched = host_ns() if ob is not None else 0
             self._in_flight.pop(i)
             self.sched.complete(d)
+            t_complete = host_ns() if ob is not None else 0
             for j, r in enumerate(d.requests):
                 r.payload.logits = logits[j]
                 r.payload.label = int(np.argmax(logits[j]))
                 r.payload.done = True
             self.served += len(d)
+            if ob is not None:
+                t1, tr = host_ns(), ob.trace
+                tr.host_span("engine.harvest", t0, t1, d.seq)
+                tr.host_span("engine.wait", t_wait, t_ready, d.seq)
+                tr.host_span("engine.fetch", t_ready, t_fetched, d.seq)
+                tr.host_span("sched.complete", t_fetched, t_complete, d.seq)
+                tr.host_span("engine.fanout", t_complete, t1, d.seq)
             n += 1
         return n
 

@@ -112,11 +112,13 @@ class ScheduledRequest:
 
 @dataclasses.dataclass
 class Dispatch:
-    """One micro-batch bound to one replica."""
+    """One micro-batch bound to one replica.  ``seq`` numbers the
+    scheduler's dispatches; the engine's host spans of the batch carry it."""
 
     requests: List[ScheduledRequest]
     replica: "ReplicaState"
     dispatch_t: float
+    seq: int
 
     def __len__(self) -> int:
         return len(self.requests)
@@ -341,6 +343,7 @@ class Scheduler:
         self.closed = False
         self.stats = LatencyStats()
         self._seq = 0
+        self._dispatch_seq = 0
         self._in_flight_reqs = 0
         # dispatches go to the least-loaded replica among the first
         # ``active`` — the autoscaling hook (repro.traffic.autoscale):
@@ -436,7 +439,10 @@ class Scheduler:
                 "sched_coalesce_hold_ms",
                 "oldest-request hold time per dispatched batch").observe(
                     (now - first) * 1e3)
-        return Dispatch(requests=batch, replica=rep, dispatch_t=now)
+        d = Dispatch(requests=batch, replica=rep, dispatch_t=now,
+                     seq=self._dispatch_seq)
+        self._dispatch_seq += 1
+        return d
 
     def next_due_at(self) -> Optional[float]:
         return self.coalescer.next_due_at(self.service_estimate_s)
@@ -498,25 +504,21 @@ class Scheduler:
             self.stats.record(r)
         rep.served += len(dispatch)
         if ob is not None:
+            # one span per batch: per-request spans would keep two objects
+            # a request alive for the whole session (the histograms below
+            # keep the per-request waits)
+            oldest = min(r.arrival for r in dispatch.requests)
             ob.trace.span("batch", cat="sched", track=f"replica{rep.index}",
-                          t0=dispatch.dispatch_t, t1=now, n=len(dispatch))
+                          t0=dispatch.dispatch_t, t1=now, n=len(dispatch),
+                          oldest_wait_ms=(dispatch.dispatch_t - oldest) * 1e3)
+            waits = ob.metrics.histogram(
+                "sched_queue_wait_ms", "admit-to-dispatch wait per request")
+            computes = ob.metrics.histogram(
+                "sched_compute_ms", "dispatch-to-complete time per request")
             for r in dispatch.requests:
-                ob.trace.span("queue_wait", cat="sched", track="requests",
-                              t0=r.arrival, t1=r.dispatch_t, seq=r.seq,
-                              priority=r.priority, replica=rep.index)
-                ob.trace.span("compute", cat="sched", track="requests",
-                              t0=r.dispatch_t, t1=now, seq=r.seq,
-                              priority=r.priority, replica=rep.index,
-                              **({} if r.deadline is None
-                                 else dict(deadline_met=bool(r.deadline_met))))
-                ob.metrics.histogram(
-                    "sched_queue_wait_ms",
-                    "admit-to-dispatch wait per request").observe(
-                        r.queue_wait * 1e3, priority=str(r.priority))
-                ob.metrics.histogram(
-                    "sched_compute_ms",
-                    "dispatch-to-complete time per request").observe(
-                        r.compute_time * 1e3, priority=str(r.priority))
+                priority = str(r.priority)
+                waits.observe(r.queue_wait * 1e3, priority=priority)
+                computes.observe(r.compute_time * 1e3, priority=priority)
                 if r.deadline is not None:
                     ob.metrics.counter(
                         "sched_deadline_total",

@@ -280,3 +280,34 @@ def test_compile_cache_defaults_to_the_checkout():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     assert CHECKOUT_CACHE_DIR == os.path.join(root, ".jax_cache")
     assert _cache_probe(None, compile_=False) == [CHECKOUT_CACHE_DIR] * 2
+
+
+# ---------------------------------------------------------------------------
+# stable device-op names
+# ---------------------------------------------------------------------------
+
+R20_BLOCKS = ["s1b0", "s1b1", "s1b2", "s2b0_ds", "s2b1", "s2b2",
+              "s3b0_ds", "s3b1", "s3b2"]
+
+
+@pytest.mark.parametrize("backend,kernels", [
+    ("pallas", ["stem"] + R20_BLOCKS),
+    (C.backends.PallasStreamBackend(cuts=[[0, 1, 2], [3], [4, 5, 6, 7, 8]]),
+     ["stem_s1b0_to_s1b2", "s2b0_ds", "s2b1_to_s3b2"]),
+    (C.backends.PallasStreamBackend(cuts=[[i] for i in range(9)],
+                                    fuse_stem=False),
+     ["stem"] + R20_BLOCKS),
+], ids=["pallas", "pallas-stream", "pallas-stream-unfused"])
+def test_kernels_carry_their_place_in_the_network(backend, kernels):
+    """Every Pallas kernel of the ResNet path is named for its place in the
+    network (the profiler's device-op name), and the input quantize runs
+    in its own named scope, in the lowered HLO."""
+    import re
+    cfg = R.RESNET20
+    qp = _qparams(cfg, seed=3)
+    cm = C.compile_model(cfg, qp, backend=backend, batch_sizes=(2,))
+    hlo = jax.jit(cm._forward).lower(cm.input_spec(2)).as_text(
+        debug_info=True)
+    found = re.findall(r'"(\w+)/pallas_call"', hlo)
+    assert sorted(set(found)) == sorted(kernels)
+    assert "/input_quantize/" in hlo

@@ -194,6 +194,10 @@ class _Poison:
         raise AssertionError(
             f"obs used while disabled (attribute {name!r})")
 
+    def __setattr__(self, name, value):
+        raise AssertionError(
+            f"obs used while disabled (set attribute {name!r})")
+
 
 def _drive_serving_path(clock):
     sched = Scheduler(2, max_batch=4, slack_s=0.002, clock=clock,
@@ -216,9 +220,21 @@ def test_disabled_serving_path_never_touches_the_session():
     """The zero-overhead contract: after disable(), a session captured
     earlier must be unreachable from the serving path — call sites must go
     through ``runtime.active()`` every time, never cache the observer."""
+    import jax
+    from repro.models import resnet as R
+    from repro.serve.engine import (
+        ImageRequest, ResNetEngine, ShardedResNetEngine)
+
     clock = FakeClock()
     ob = obsrt.instrument(clock=clock)
     sched = Scheduler(2, max_batch=4, clock=clock)   # built while enabled
+    # the engines' host spans and the model's put/launch spans too
+    qp = R.quantize_params(R.fold_params(
+        R.init_params(R.RESNET8, jax.random.PRNGKey(0))), R.RESNET8)
+    engines = [ResNetEngine(R.RESNET8, qp, batch=2, backend="lax-int"),
+               ShardedResNetEngine(R.RESNET8, qp, batch=2,
+                                   backend="lax-int", replicas=1,
+                                   slack_ms=0.0)]
     obsrt.disable()
     assert obsrt.active() is None
     ob.metrics = ob.trace = _Poison()                # detonate any later use
@@ -230,6 +246,13 @@ def test_disabled_serving_path_never_touches_the_session():
     sched.set_active(1)
     _drive_serving_path(clock)                       # fresh sched, still off
     assert sched.summary()["count"] == 4
+    image = np.zeros((32, 32, 3), np.float32)
+    for eng in engines:
+        reqs = [ImageRequest(rid=i, image=image) for i in range(3)]
+        for r in reqs:
+            eng.submit(r)
+        eng.run()
+        assert all(r.done for r in reqs)
 
 
 def test_instrumented_context_manager_always_uninstalls():
@@ -262,16 +285,20 @@ def test_scheduler_spans_match_virtual_timestamps():
     s = sched.summary()
     assert ob.metrics.total("sched_submitted_total") == 8
     assert ob.metrics.total("sched_served_total") == s["count"] == 8
-    waits = [e for e in ob.trace.events if e.name == "queue_wait"]
-    computes = [e for e in ob.trace.events if e.name == "compute"]
-    assert len(waits) == len(computes) == 8
-    assert all(e.track == "requests" and e.cat == "sched" for e in waits)
+    batches = [e for e in ob.trace.events if e.name == "batch"]
+    assert len(batches) == ob.metrics.total("sched_dispatches_total") == 2
+    assert all(e.cat == "sched" and e.track.startswith("replica")
+               for e in batches)
+    assert sum(e.args["n"] for e in batches) == 8
+    # no per-request spans: the histograms keep the per-request waits
+    assert not {"queue_wait", "compute"} & {e.name for e in ob.trace.events}
     # span endpoints are the scheduler's own stamps, in FakeClock seconds:
     # all 8 admitted at t=0, first batch dispatched at t=0.01, the second
-    # one complete-cycle (0.001s) later
-    assert {round(e.dur, 6) for e in waits} == {0.01, 0.011}
-    assert [e.args["seq"] for e in computes] == \
-        [w.args["seq"] for w in waits]
+    # one complete-cycle (0.001s) later; each completes 0.001s after
+    assert [round(e.ts, 6) for e in batches] == [0.01, 0.011]
+    assert all(round(e.dur, 6) == 0.001 for e in batches)
+    assert [round(e.args["oldest_wait_ms"], 6) for e in batches] == \
+        [10.0, 11.0]
     holds = [e for e in ob.trace.events if e.name == "coalesce_hold"]
     assert len(holds) == ob.metrics.total("sched_dispatches_total")
     h = ob.metrics.get("sched_queue_wait_ms")
@@ -510,13 +537,17 @@ def _stripped_chrome(path):
 @pytest.mark.slow
 def test_traffic_cli_exports_trace_with_kernel_profiles(tmp_path):
     """The acceptance pin: a seeded sim run with --trace-out produces a
-    Perfetto-loadable Chrome trace carrying per-request spans AND per-task
-    kernel profiles with measured-vs-modeled HBM ratios."""
+    Perfetto-loadable Chrome trace carrying per-batch scheduler spans AND
+    per-task kernel profiles with measured-vs-modeled HBM ratios."""
     from repro.obs.__main__ import load_chrome_trace
     d, report = _run_traffic(tmp_path, "a")
     events = load_chrome_trace(str(d / "trace.json"))    # validates shape
     names = {e.get("name") for e in events}
-    assert {"queue_wait", "compute", "coalesce_hold"} <= names
+    assert {"batch", "coalesce_hold"} <= names
+    assert not {"queue_wait", "compute"} & names
+    for e in events:
+        if e.get("name") == "batch":
+            assert e["args"]["n"] >= 1 and e["args"]["oldest_wait_ms"] >= 0
     kernels = [e for e in events if e.get("cat") == "kernel"]
     assert kernels, "no kernel-profile spans in the trace"
     for e in kernels:

@@ -193,3 +193,28 @@ def test_selective_scan_compiles_for_v5e(one_chip):
                    ((B, S, di), f32), ((B, S, di), f32), ((di, n), f32),
                    ((B, S, n), f32), ((B, S, n), f32), ((B, di, n), f32))
     assert "tpu_custom_call" in hlo
+
+
+def test_kernel_op_names_for_v5e(one_chip, monkeypatch):
+    """The device ops of the compiled ResNet8 forward are the kernels'
+    names, the names a profile of the chip shows: ``%s1b0.<n> =
+    custom-call(...)``."""
+    import re
+
+    from repro.compile import compile_model
+    from repro.kernels.conv_stem import ops as stem_ops
+    from repro.kernels.resblock_fused import ops as block_ops
+    for mod in (stem_ops, block_ops):
+        monkeypatch.setattr(mod, "use_interpret", lambda: False)
+    cfg = R.RESNET8
+    params = R.init_params(cfg, jax.random.PRNGKey(0))
+    cm = compile_model(cfg, R.quantize_params(R.fold_params(params), cfg),
+                       batch_sizes=(24,))
+    jax.clear_caches()            # no interpret-mode trace of these shapes
+    try:
+        hlo = jax.jit(cm._forward).lower(
+            cm.input_spec(24, sharding=one_chip)).compile().as_text()
+    finally:
+        jax.clear_caches()        # and no Mosaic one left for CPU tests
+    ops = re.findall(r"%(\w+)\.\d+ = \S+ custom-call\(", hlo)
+    assert sorted(ops) == ["s1b0", "s2b0_ds", "s3b0_ds", "stem"]
