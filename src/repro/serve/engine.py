@@ -18,6 +18,7 @@ retracing.
 from __future__ import annotations
 
 import asyncio
+import collections
 import dataclasses
 from typing import List, Optional
 
@@ -214,6 +215,9 @@ class ResNetEngine:
         self.queue: List[ImageRequest] = []
         self.served = 0
         self.ticks = 0                    # batches served (the batch id)
+        # (requests, images, logits) of the launched batches not yet
+        # completed, oldest first: batch ids ticks, ticks + 1 (one ahead)
+        self._in_flight: collections.deque = collections.deque()
 
     def submit(self, req: ImageRequest):
         """Enqueue one request (shape-validated at admission)."""
@@ -221,26 +225,31 @@ class ResNetEngine:
         self.queue.append(req)
 
     def tick(self) -> bool:
-        """Serve one batch; returns False when the queue is empty.
+        """Complete one batch, keeping the next one in flight behind it;
+        returns False once the queue and the in-flight batches are empty.
 
-        Under a live obs session the tick records its host spans, all
-        carrying the tick's number: ``engine.tick`` around ``engine.gather``
-        (queue slice + stack), the model's ``model.put``/``model.launch``,
+        A one-deep software pipeline: with nothing in flight the tick first
+        launches a batch, then launches the next one while the queue holds
+        requests, and only then blocks on the oldest in-flight batch.  JAX
+        dispatches asynchronously, so the next batch's input copy and
+        forward queue behind the current one on the device while the host
+        reads back, fans out and refills.  Requests complete in FIFO order.
+
+        Under a live obs session ``engine.tick`` spans the launches (see
+        ``_launch``) and the completion of the oldest batch:
         ``engine.wait`` (the device), ``engine.fetch`` (readback) and
-        ``engine.fanout`` (per-request results)."""
-        if not self.queue:
+        ``engine.fanout`` (per-request results).  These four carry the id
+        of the batch the tick completes."""
+        if not self.queue and not self._in_flight:
             return False
         ob = _obs.active()
         t0 = host_ns() if ob is not None else 0
-        reqs = self.queue[:self.batch]
-        del self.queue[:len(reqs)]
-        dtype = _input_contract(self.cfg)[1]
-        imgs = np.stack([np.asarray(r.image, dtype) for r in reqs])
-        if ob is not None:
-            t_gather = host_ns()
-            mark = ob.trace.host_mark()
-        out = self.model(imgs)
-        t_launched = host_ns() if ob is not None else 0
+        if not self._in_flight:
+            self._launch(ob)
+        if self.queue:
+            self._launch(ob)
+        reqs, imgs, out = self._in_flight.popleft()
+        t_wait = host_ns() if ob is not None else 0
         jax.block_until_ready(out)
         t_ready = host_ns() if ob is not None else 0
         logits = np.asarray(out)
@@ -269,18 +278,45 @@ class ResNetEngine:
         self.served += len(reqs)
         if ob is not None:
             t1, tr, bid = host_ns(), ob.trace, self.ticks
-            tr.host_claim(mark, bid)          # the model's put and launch
             tr.host_span("engine.tick", t0, t1, bid)
-            tr.host_span("engine.gather", t0, t_gather, bid)
-            tr.host_span("engine.wait", t_launched, t_ready, bid)
+            tr.host_span("engine.wait", t_wait, t_ready, bid)
             tr.host_span("engine.fetch", t_ready, t_fetched, bid)
             tr.host_span("engine.fanout", t_fanout, t1, bid)
         self.ticks += 1
         return True
 
+    def _launch(self, ob) -> None:
+        """Take up to ``batch`` requests off the queue, stack them and
+        launch the model on them without waiting.  Under a live obs session
+        ``engine.gather`` (queue slice + stack) and the model's
+        ``model.put``/``model.launch`` carry the id of the batch launched,
+        and ``engine_overlapped_launches_total`` counts the launches made
+        while an earlier batch was still in flight."""
+        t0 = host_ns() if ob is not None else 0
+        reqs = self.queue[:self.batch]
+        del self.queue[:len(reqs)]
+        dtype = _input_contract(self.cfg)[1]
+        imgs = np.stack([np.asarray(r.image, dtype) for r in reqs])
+        if ob is not None:
+            t_gather = host_ns()
+            mark = ob.trace.host_mark()
+        out = self.model(imgs)
+        if ob is not None:
+            bid = self.ticks + len(self._in_flight)
+            ob.trace.host_claim(mark, bid)    # the model's put and launch
+            ob.trace.host_span("engine.gather", t0, t_gather, bid)
+            if self._in_flight:
+                ob.metrics.counter(
+                    "engine_overlapped_launches_total",
+                    "batches launched while an earlier batch was in "
+                    "flight").inc()
+        self._in_flight.append((reqs, imgs, out))
+
     def run(self, max_ticks: int = 10_000) -> int:
+        """Tick until the queue and the in-flight batches are empty;
+        returns the ticks, one a batch."""
         ticks = 0
-        while self.queue and ticks < max_ticks:
+        while (self.queue or self._in_flight) and ticks < max_ticks:
             self.tick()
             ticks += 1
         return ticks

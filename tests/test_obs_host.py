@@ -2,7 +2,8 @@
 ``gc`` span, the profiler's time base, and what recording costs.
 
 * Under a live session each engine records one nest of named host spans a
-  batch, all carrying the batch's id, each child inside its parent.
+  batch, each child inside its parent and carrying its batch's id;
+  ``ResNetEngine``'s tick also holds the launch of the batch after its own.
 * A live session exports on ``time.time_ns`` (the JAX profiler's base),
   its debug bundles too; a ``FakeClock`` session keeps its virtual
   seconds and records no wall-timed collections.
@@ -26,8 +27,8 @@ from repro.serve.engine import (ImageRequest, ResNetEngine,
                                 ShardedResNetEngine)
 from repro.serve.sched import FakeClock
 
-TICK = ("engine.gather", "model.put", "model.launch", "engine.wait",
-        "engine.fetch", "engine.fanout")
+LAUNCH = ("engine.gather", "model.put", "model.launch")
+COMPLETE = ("engine.wait", "engine.fetch", "engine.fanout")
 DISPATCH = ("sched.poll", "engine.gather", "model.put", "model.launch")
 HARVEST = ("engine.wait", "engine.fetch", "sched.complete", "engine.fanout")
 
@@ -62,15 +63,17 @@ def _by_batch(spans, names):
     return out
 
 
+def _assert_in_order(parent, children, where):
+    prev, end = parent
+    for c0, c1 in children:                # in order, inside the parent
+        assert prev <= c0 <= c1 <= end, (where, parent, children)
+        prev = c1
+
+
 def _assert_nest(batches, parent, children):
     for bid, sp in batches.items():
         assert set(sp) == {parent, *children}, (bid, sorted(sp))
-        p0, p1 = sp[parent]
-        prev = p0
-        for c in children:                 # in order, inside the parent
-            c0, c1 = sp[c]
-            assert prev <= c0 <= c1 <= p1, (bid, c, sp)
-            prev = c1
+        _assert_in_order(sp[parent], [sp[c] for c in children], bid)
 
 
 def test_resnet_engine_records_one_nest_per_batch(qp8):
@@ -80,9 +83,19 @@ def test_resnet_engine_records_one_nest_per_batch(qp8):
         eng.submit(r)
     assert eng.run() == 3
     obsrt.disable()
-    batches = _by_batch(ob.trace.host_spans(), ("engine.tick",) + TICK)
+    batches = _by_batch(ob.trace.host_spans(),
+                        ("engine.tick",) + LAUNCH + COMPLETE)
     assert sorted(batches) == [0, 1, 2]
-    _assert_nest(batches, "engine.tick", TICK)
+    for bid, sp in batches.items():
+        assert set(sp) == {"engine.tick", *LAUNCH, *COMPLETE}, bid
+    # tick k launches batch k + 1 (tick 0 launches batch 0 first), then
+    # waits on, fetches and fans out batch k, all inside its engine.tick
+    for k, sp in batches.items():
+        launched = [0] * (k == 0) + [k + 1] * (k + 1 in batches)
+        children = [batches[b][n] for b in launched for n in LAUNCH] + \
+            [sp[n] for n in COMPLETE]
+        _assert_in_order(sp["engine.tick"], children, k)
+    assert ob.metrics.total("engine_overlapped_launches_total") == 2
 
 
 def test_sharded_engine_records_dispatch_and_harvest_nests(qp8):

@@ -1,11 +1,12 @@
 """Serving layer: ResNetEngine on CompiledModel (backend parity, zero-pad
-short batches, bucket selection, no per-tick retracing, A/B hooks) and the
-LM Engine admission regressions."""
+short batches, bucket selection, no per-tick retracing, A/B hooks, the
+one-batch-ahead pipeline) and the LM Engine admission regressions."""
 import jax
 import numpy as np
 import pytest
 
 from repro.models import resnet as R
+from repro.obs import runtime as obsrt
 from repro.serve.engine import Engine, ImageRequest, Request, ResNetEngine
 
 
@@ -111,6 +112,93 @@ def test_engine_rejects_batch_outside_buckets(qp8):
     with pytest.raises(ValueError, match="batch_sizes"):
         ResNetEngine(R.RESNET8, qp8, batch=8, backend="lax-int",
                      batch_sizes=(2, 4))
+
+
+# ---------------------------------------------------------------------------
+# the one-batch-ahead pipeline: a tick launches the next batch before it
+# blocks on the oldest one in flight
+# ---------------------------------------------------------------------------
+
+
+def _requests(imgs):
+    return [ImageRequest(rid=i, image=img) for i, img in enumerate(imgs)]
+
+
+@pytest.mark.parametrize("backend", ["lax-int", "pallas"])
+def test_pipelined_serving_matches_one_request_at_a_time(qp8, images,
+                                                         backend):
+    one = ResNetEngine(R.RESNET8, qp8, batch=2, backend=backend)
+    alone = []
+    for r in _requests(images):              # nothing queued behind it
+        one.submit(r)
+        assert one.run() == 1
+        alone.append(r.logits)
+    eng = ResNetEngine(R.RESNET8, qp8, batch=2, backend=backend)
+    piped = _serve(eng, images)
+    np.testing.assert_array_equal(np.stack([r.logits for r in piped]),
+                                  np.stack(alone))
+
+
+def test_pipelined_ticks_complete_one_batch_each_in_fifo_order(qp8, images):
+    eng = ResNetEngine(R.RESNET8, qp8, batch=2, backend="lax-int")
+    reqs = _requests(images[:5])             # batches of 2, 2 and 1
+    for r in reqs:
+        eng.submit(r)
+    for t, served in enumerate((2, 4, 5)):
+        assert eng.tick()
+        assert [r.done for r in reqs] == [i < served for i in range(5)]
+        assert eng.served == served and eng.ticks == t + 1
+    assert not eng.tick()
+
+
+def test_tick_is_false_only_once_queue_and_in_flight_are_empty(qp8, images):
+    eng = ResNetEngine(R.RESNET8, qp8, batch=2, backend="lax-int")
+    reqs = _requests(images[:4])
+    for r in reqs:
+        eng.submit(r)
+    assert eng.tick()                        # launched both, completed one
+    assert not eng.queue and not reqs[2].done
+    assert eng.tick()                        # the batch still in flight
+    assert all(r.done for r in reqs)
+    assert not eng.tick() and eng.ticks == 2
+
+
+def test_run_returns_the_batches_and_drains_the_in_flight_one(qp8, images):
+    eng = ResNetEngine(R.RESNET8, qp8, batch=2, backend="lax-int")
+    reqs = _requests(images[:5])
+    for r in reqs:
+        eng.submit(r)
+    assert eng.run() == 3 and all(r.done for r in reqs)
+    reqs = _requests(images[:4])
+    for r in reqs:
+        eng.submit(r)
+    eng.tick()                               # leaves a batch in flight
+    assert not eng.queue
+    assert eng.run() == 1 and all(r.done for r in reqs)
+    assert eng.served == 9 and eng.ticks == 5
+
+
+def test_ab_shadow_replays_the_batch_it_completes(qp8, images):
+    """Every batch holds other images, so a shadow that replayed the batch
+    just launched, not the one completed, would read a deviation."""
+    eng = ResNetEngine(R.RESNET8, qp8, batch=2, backend="lax-int",
+                       ab_backends=("lax-int",))
+    _serve(eng, images)
+    assert eng.ab_stats["lax-int"] == [0.0, 0.0, 0.0]
+
+
+def test_overlapped_launches_count_all_but_the_first_of_a_drained_run(
+        qp8, images):
+    eng = ResNetEngine(R.RESNET8, qp8, batch=2, backend="lax-int")
+    ob = obsrt.instrument()
+    try:
+        _serve(eng, images[:5])              # 3 launches
+        first = ob.metrics.total("engine_overlapped_launches_total")
+        _serve(eng, images)                  # 3 more, after a drain
+    finally:
+        obsrt.disable()
+    assert first == 2
+    assert ob.metrics.total("engine_overlapped_launches_total") == 4
 
 
 # ---------------------------------------------------------------------------
