@@ -7,10 +7,11 @@
 For each of ``--seeds`` it runs the cell (a short window at the cell's own
 load, the whole timed path) and prints the numbers the check compared.
 For each of ``--control-seeds`` it puts the control in the program's
-place: the plain reference at int4 (``reference.forward(bits=4)``), on as
-many images of that seed's pool as a run compares, against the reference
-at int8.  A sound program reads at or under each limit; the control has to
-read over one.  The benchmark's own runs never run the control.
+place: the plain reference at int4 (``forward(bits=4)`` of the family's
+reference), on as many images of that seed's pool as a run compares,
+against the reference at int8; the control alone imports nothing of the
+program.  A sound program reads at or under each limit; the control has
+to read over one.  The benchmark's own runs never run the control.
 """
 from __future__ import annotations
 
@@ -26,17 +27,17 @@ from bench import run  # noqa: E402
 
 
 def control_reading(cell, seed: int) -> dict:
-    from bench import check, reference
+    from bench import check
     from bench.traffic import generate
 
-    cfg, mix = cell.config, cell.mix
+    cfg, mix, reference = cell.config, cell.mix, cell.family.reference
     net = reference.build_net(cfg)
     weights = reference.make_weights(net, cfg["weight_seed"])
     pool = generate.image_pool(seed, mix["pool_images"], cfg["img"],
                                cfg["in_channels"])
     served = [True] * mix["pool_images"]      # every pool image, once
-    return check.check(net, weights, pool, served, seed, mix["sample"],
-                       cfg["limits"], bits=4)
+    return check.check(reference, net, weights, pool, served, seed,
+                       mix["sample"], cfg["limits"], bits=4)
 
 
 def main() -> int:

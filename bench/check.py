@@ -2,20 +2,23 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the requests the window served, drawn from the seed, is run through the
-plain reference (``reference.py``) on the same images, and two numbers are
-compared, each with its limit from the configuration file's ``limits``:
+plain reference of the configuration's family (the ``reference`` module
+``families.load`` returns, ``bench/families/<family>/reference.py``) on the
+same images, and two numbers are compared, each with its limit from the
+configuration file's ``limits``:
 
 * ``logit_max_abs_diff``: the largest |served logit - reference logit|
   over the sample.  The integer pipeline is exact and its last step is one
   float multiply by a power of two and one float add, so a sound program
   reads 0 and the limit is 0.
 * ``unserved``: requests sent that never got an answer.
+
+It imports nothing of the program, and names no family.
 """
 from __future__ import annotations
 
 import numpy as np
 
-from bench import reference
 from bench.traffic import generate
 
 
@@ -24,11 +27,13 @@ def logit_gap(served: np.ndarray, ref: np.ndarray) -> float:
     return float(np.nan_to_num(d, nan=np.inf).max())
 
 
-def check(net, weights, pool: np.ndarray, served: list, seed: int,
-          sample: int, limits: dict, bits: int = 8) -> dict:
-    """``served[i]``: the logits request ``i`` got (None if it got none);
-    request ``i`` carried ``pool[i % len(pool)]``.  ``bits`` < 8 compares
-    the control (the reference at that precision) in the program's place."""
+def check(reference, net, weights, pool: np.ndarray, served: list,
+          seed: int, sample: int, limits: dict, bits: int = 8) -> dict:
+    """``reference``: the family's reference module, ``net`` and
+    ``weights`` made by it.  ``served[i]``: the logits request ``i`` got
+    (None if it got none); request ``i`` carried ``pool[i % len(pool)]``.
+    ``bits`` < 8 compares the control (the reference at that precision)
+    in the program's place."""
     done = np.array([i for i, s in enumerate(served) if s is not None])
     unserved = len(served) - len(done)
     idx = done[generate.sample(seed, len(done), sample)] if len(done) else done

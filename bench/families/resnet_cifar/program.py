@@ -1,5 +1,7 @@
-"""The system under test, as the benchmark drives it: the one module of
-``bench/`` that imports the program (``src/repro``).
+"""The system under test, as the benchmark drives the CIFAR ResNet family:
+the family's adapter.  With the other families' adapters, the only
+modules of ``bench/`` that import the program's models and engines
+(``src/repro``).
 
 It turns a configuration file and the benchmark's seeded weights into the
 program's own types, and builds the engine a traffic mix names.  The
@@ -10,8 +12,8 @@ from __future__ import annotations
 import os
 import sys
 
-SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
-    __file__))), "src")
+SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "..", "..",
+                                   "src"))
 if SRC not in sys.path:
     sys.path.insert(0, SRC)
 
@@ -19,10 +21,10 @@ from repro.compile.params import (  # noqa: E402
     QBlockParams, QConvParams, QLinearParams, QResNetParams)
 from repro.core.quant import QSpec  # noqa: E402
 from repro.models.resnet import ResNetConfig  # noqa: E402
-from repro.serve import ImageRequest  # noqa: E402
+from repro.serve import ImageRequest as Request  # noqa: E402
 from repro.serve.engine import ResNetEngine, ShardedResNetEngine  # noqa: E402
 
-__all__ = ["ImageRequest", "build_engine", "program_config",
+__all__ = ["Request", "build_engine", "program_config",
            "program_params"]
 
 
@@ -34,8 +36,8 @@ def program_config(cfg: dict) -> ResNetConfig:
 
 
 def program_params(net, weights: dict) -> QResNetParams:
-    """The benchmark's weights (``reference.make_weights``) as the
-    program's typed parameters, with the grids ``net`` states."""
+    """The benchmark's weights (``reference.make_weights`` of this family)
+    as the program's typed parameters, with the grids ``net`` states."""
     def conv(c):
         w, b = weights[c.name]
         return QConvParams(wq=w, bq=b, w_spec=QSpec(8, True, c.w_exp),

@@ -18,6 +18,8 @@ scheme (arXiv:2309.15631 §III-A):
 
 Each conv runs as ``lax.conv_general_dilated`` on int32 operands with an
 int32 result: the plain, exact definition, with no precision to choose.
+That conv, the rounding shift, the control's coarsening and the blockwise
+loop are the families' shared helpers (``bench/families/common.py``).
 
 The weights are made here too, from the seed alone, in one jitted call on
 the device: the program is handed these arrays, and the reference never
@@ -30,7 +32,6 @@ activations), the next precision below the configuration's int8.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import List, Optional, Tuple
 
@@ -38,25 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-
-@dataclasses.dataclass(frozen=True)
-class ConvShape:
-    name: str            # "stem", "b{i}.conv0", "b{i}.conv1", "b{i}.ds"
-    k: int               # square kernel size
-    cin: int
-    cout: int
-    stride: int
-    hin: int             # input height (= width)
-    w_exp: int           # pow2 exponent of the s8 weights
-    x_exp: int           # pow2 exponent of the u8 input
-
-    @property
-    def hout(self) -> int:
-        return self.hin // self.stride
-
-    @property
-    def b_exp(self) -> int:
-        return self.x_exp + self.w_exp
+from bench.families.common import (ConvShape, blockwise, coarsen, conv,
+                                   seed_key, shift, w_exp)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -86,13 +70,6 @@ class Net:
         return out
 
 
-def _w_exp(fan_in: int, gain: float) -> int:
-    """Pow2 weight grid that covers ~4.5 standard deviations of an He-init
-    normal of this fan-in with the s8 range."""
-    sigma = gain * math.sqrt(2.0 / fan_in)
-    return int(math.ceil(math.log2(4.5 * sigma / 127.0)))
-
-
 # He-init scale factors: the stem is drawn 4x larger, so the u8 maps use
 # more of their range than the [0, 1) image gives them; conv1 of every
 # block (the add-fold conv) 2x smaller, so the residual stream neither
@@ -108,7 +85,7 @@ def build_net(cfg: dict) -> Net:
     a_exp, in_exp = cfg["act_exp"], cfg["input_exp"]
     side = cfg["img"]
     stem = ConvShape("stem", 3, cfg["in_channels"], w0, 1, side,
-                     _w_exp(9 * cfg["in_channels"], STEM_GAIN), in_exp)
+                     w_exp(9 * cfg["in_channels"], STEM_GAIN), in_exp)
     blocks = []
     cin = w0
     for stage in range(cfg["stages"]):
@@ -117,29 +94,20 @@ def build_net(cfg: dict) -> Net:
             i = len(blocks)
             stride = 2 if (stage > 0 and bi == 0) else 1
             c0 = ConvShape(f"b{i}.conv0", 3, cin, cout, stride, side,
-                           _w_exp(9 * cin, 1.0), a_exp)
+                           w_exp(9 * cin, 1.0), a_exp)
             side //= stride
             c1 = ConvShape(f"b{i}.conv1", 3, cout, cout, 1, side,
-                           _w_exp(9 * cout, CONV1_GAIN), a_exp)
+                           w_exp(9 * cout, CONV1_GAIN), a_exp)
             ds = None
             if stride != 1 or cin != cout:
                 ds = ConvShape(f"b{i}.ds", 1, cin, cout, stride, side * stride,
-                               _w_exp(cin, 1.0), a_exp)
+                               w_exp(cin, 1.0), a_exp)
             blocks.append(Block(c0, c1, ds))
             cin = cout
     return Net(stem=stem, blocks=tuple(blocks), fc_in=cin,
                num_classes=cfg["num_classes"],
-               fc_w_exp=_w_exp(cin, 0.5), act_exp=a_exp, input_exp=in_exp,
+               fc_w_exp=w_exp(cin, 0.5), act_exp=a_exp, input_exp=in_exp,
                head_hw=side)
-
-
-def seed_key(seed: int) -> jax.Array:
-    """A PRNG key from any non-negative whole number, 64-bit ones too."""
-    seed = int(seed)
-    if seed < 0:
-        raise ValueError(f"seed must be >= 0: {seed}")
-    key = jax.random.PRNGKey(seed & 0xFFFFFFFF)
-    return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
 def _gain(c: ConvShape) -> float:
@@ -180,31 +148,6 @@ def make_weights(net: Net, seed: int) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def _shift(acc, s: int):
-    """acc * 2**-s on integers: a rounding right shift ``(acc + 2**(s-1))
-    >> s`` (floor(x + 0.5)) for s > 0, a left shift for s < 0."""
-    if s > 0:
-        return (acc + (1 << (s - 1))) >> s
-    if s < 0:
-        return acc << (-s)
-    return acc
-
-
-def _conv(x, w, stride: int):
-    return jax.lax.conv_general_dilated(
-        x.astype(jnp.int32), w.astype(jnp.int32), (stride, stride), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        preferred_element_type=jnp.int32)
-
-
-def _coarsen(q, drop: int, lo: int, hi: int):
-    """Round integers onto a grid 2**drop coarser (half away from zero)."""
-    if drop == 0:
-        return q
-    f = q.astype(jnp.float32) * 2.0 ** -drop
-    return jnp.clip(jnp.sign(f) * jnp.floor(jnp.abs(f) + 0.5), lo, hi)
-
-
 def forward(net: Net, weights: dict, images, bits: int = 8):
     """Logits (N, classes) float32 of float images (N, H, W, 3) in [0, 1).
 
@@ -217,56 +160,39 @@ def forward(net: Net, weights: dict, images, bits: int = 8):
 
     def param(c: ConvShape):
         w, b = weights[c.name]
-        w = _coarsen(w.astype(jnp.int32), drop, wlo, whi).astype(jnp.int32)
-        b = _coarsen(b.astype(jnp.int32), 2 * drop, -32768, 32767)
+        w = coarsen(w.astype(jnp.int32), drop, wlo, whi).astype(jnp.int32)
+        b = coarsen(b.astype(jnp.int32), 2 * drop, -32768, 32767)
         return w, b.astype(jnp.int32), c.w_exp + drop, c.x_exp + drop
 
-    def conv(x, c: ConvShape):
-        w, b, w_exp, x_exp = param(c)
-        return _conv(x, w, c.stride) + b, x_exp + w_exp
+    def conv_bias(x, c: ConvShape):
+        w, b, wx, xx = param(c)
+        return conv(x, w, c.stride) + b, xx + wx
 
     def relu_requant(acc, p_exp: int):
-        return jnp.clip(_shift(jnp.maximum(acc, 0), a_exp - p_exp), 0, amax)
+        return jnp.clip(shift(jnp.maximum(acc, 0), a_exp - p_exp), 0, amax)
 
     in_exp = net.input_exp + drop
     f = images.astype(jnp.float32) * 2.0 ** -in_exp
     h = jnp.clip(jnp.sign(f) * jnp.floor(jnp.abs(f) + 0.5), 0, amax)
-    h = relu_requant(*conv(h.astype(jnp.int32), net.stem))
+    h = relu_requant(*conv_bias(h.astype(jnp.int32), net.stem))
     for blk in net.blocks:
-        y = relu_requant(*conv(h, blk.conv0))
-        acc1, p1 = conv(y, blk.conv1)
+        y = relu_requant(*conv_bias(h, blk.conv0))
+        acc1, p1 = conv_bias(y, blk.conv1)
         if blk.ds is not None:
-            skip, p_skip = conv(h, blk.ds)
+            skip, p_skip = conv_bias(h, blk.ds)
         else:
             skip, p_skip = h, a_exp
-        h = relu_requant(acc1 + _shift(skip, p1 - p_skip), p1)
+        h = relu_requant(acc1 + shift(skip, p1 - p_skip), p1)
     w, b = weights["fc"]
-    w = _coarsen(w.astype(jnp.int32), drop, wlo, whi).astype(jnp.int32)
+    w = coarsen(w.astype(jnp.int32), drop, wlo, whi).astype(jnp.int32)
     pooled = jnp.sum(h, axis=(1, 2))
     acc = jnp.dot(pooled, w, preferred_element_type=jnp.int32)
     scale = 2.0 ** (a_exp + net.fc_w_exp + drop) / net.head_hw ** 2
     return acc.astype(jnp.float32) * jnp.float32(scale) + b
 
 
-@functools.lru_cache(maxsize=None)
-def _jitted(net: Net, bits: int):
-    return jax.jit(lambda weights, x: forward(net, weights, x, bits=bits))
-
-
 def reference_logits(net: Net, weights: dict, images: np.ndarray,
                      bits: int = 8, block: int = 256) -> np.ndarray:
-    """:func:`forward` over ``images`` in blocks of ``block`` rows (the last
-    block zero-padded, so one program serves every block).  The weights are
-    an argument of that program, not constants in it, so one compile serves
-    every seed."""
-    fn = _jitted(net, bits)
-    n = len(images)
-    out = np.empty((n, net.num_classes), np.float32)
-    for i in range(0, n, block):
-        x = np.asarray(images[i:i + block], np.float32)
-        m = len(x)
-        if m < block:
-            x = np.concatenate([x, np.zeros((block - m,) + x.shape[1:],
-                                            np.float32)])
-        out[i:i + m] = np.asarray(fn(weights, x))[:m]
-    return out
+    """:func:`forward` over ``images`` in blocks of ``block`` rows
+    (``common.blockwise``)."""
+    return blockwise(forward, net, weights, images, bits, block)

@@ -6,17 +6,21 @@
 
 The cell is an entry of ``workloads`` in ``BENCHMARK.json``; it names a
 configuration (``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<traffic>.json``).  In one process the run
+(``bench/traffic/<traffic>.json``).  The configuration's ``family`` key
+chooses the package ``bench/families/<family>/`` (``families.load``) that
+holds every family-specific step: the plain reference (``reference.py``)
+and the adapter to the program (``program.py``); this module names no
+family.  In one process the run
 
 1. makes the weights on the device from the configuration's
-   ``weight_seed`` and the images from ``--seed`` (``reference.py``,
-   ``traffic/generate.py``), and builds the engine the mix names
-   (``program.py``);
+   ``weight_seed`` (the family's ``reference.make_weights``) and the images
+   from ``--seed`` (``traffic/generate.py``), and builds the engine the mix
+   names (the family's ``program.build_engine``);
 2. compiles and warms every batch shape the mix will use (set-up);
 3. drives the engine for ``--seconds`` (``traffic/drive.py``), with the
    profiler on when ``--trace 1``;
 4. frees the program, then checks a seeded sample of the served logits
-   against the plain reference (``check.py``);
+   against the family's plain reference (``check.py``);
 5. prints, as the last line of stdout, one JSON object: ``correct``,
    ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1``
    also ``breakdown``, and last ``checks`` (each compared number with its
@@ -67,6 +71,7 @@ def log(msg: str) -> None:
 class Cell:
     workload: dict
     config: dict
+    family: Any                   # families.Family, by config["family"]
     mix: dict
     end_to_end: list              # BENCHMARK.json entries this cell reports
     per_layer: list
@@ -81,6 +86,7 @@ def _applies(metric: dict, cell: str) -> bool:
 
 
 def load_cell(name: str, root: str = ROOT) -> Cell:
+    from bench import families
     from bench.traffic.generate import load_mix
 
     with open(os.path.join(root, "BENCHMARK.json")) as f:
@@ -93,7 +99,8 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     cfg = next(c for c in spec["configs"] if c["name"] == w["config"])
     with open(os.path.join(root, cfg["file"])) as f:
         config = json.load(f)
-    return Cell(workload=w, config=config, mix=load_mix(w["traffic"]),
+    return Cell(workload=w, config=config, family=families.load(config),
+                mix=load_mix(w["traffic"]),
                 end_to_end=[m for m in spec["end_to_end"]
                             if _applies(m, name)],
                 per_layer=[m for m in spec["per_layer"] if _applies(m, name)])
@@ -210,11 +217,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     import jax
     import numpy as np
 
-    from bench import check, program, reference, work
+    from bench import check, work
     from bench import trace as tr
     from bench.traffic import drive, generate
 
     cfg, mix = cell.config, cell.mix
+    reference, program = cell.family.reference, cell.family.program
     used = devices[:cell.chips]
     CompileCount.start()
     n_hits, n_misses = CompileCount.hits, CompileCount.misses
@@ -243,7 +251,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
                            time.perf_counter() - gc_t.pop()))
 
     def make_request(i):
-        return program.ImageRequest(rid=i, image=pool[i % len(pool)])
+        return program.Request(rid=i, image=pool[i % len(pool)])
 
     span = drive.Spans()
     log_dir = None
@@ -311,8 +319,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     del engine, run, win
     gc.collect()
     t0 = time.monotonic()
-    checks = check.check(net, weights, pool, served, seed, mix["sample"],
-                         cfg["limits"])
+    checks = check.check(reference, net, weights, pool, served, seed,
+                         mix["sample"], cfg["limits"])
     log(f"reference check of {min(mix['sample'], len(served))} requests: "
         f"{time.monotonic() - t0:.3f} s")
     out = dict(correct=all(v["value"] <= v["limit"]

@@ -6,6 +6,8 @@ import re
 
 import pytest
 
+from bench import families
+
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -50,6 +52,8 @@ def test_configs(spec):
         assert cfg["reduced"] == c["reduced"]
         assert all(NAME.match(k) for k in c["reduced"])
         assert set(cfg["limits"]) == {"logit_max_abs_diff", "unserved"}
+        # the family package that holds its reference and program adapter
+        assert families.load(cfg).name == cfg["family"]
         # one weight set a configuration, so every run after a checkout's
         # first finds its programs in the compilation cache
         assert isinstance(cfg["weight_seed"], int) and cfg["weight_seed"] >= 0

@@ -7,7 +7,7 @@ import os
 import numpy as np
 import pytest
 
-from bench import check, reference
+from bench import check, families
 from bench.traffic import generate
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -19,11 +19,13 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 def test_control_fails(name, seed):
     with open(os.path.join(ROOT, "bench", "configs", f"{name}.json")) as f:
         cfg = json.load(f)
+    reference = families.load(cfg).reference
     net = reference.build_net(cfg)
     w = reference.make_weights(net, seed)
     pool = generate.image_pool(seed, 32, cfg["img"], cfg["in_channels"])
     served = [True] * len(pool)
-    c = check.check(net, w, pool, served, seed, 16, cfg["limits"], bits=4)
+    c = check.check(reference, net, w, pool, served, seed, 16,
+                    cfg["limits"], bits=4)
     assert c["logit_max_abs_diff"]["value"] > 0.1
     assert c["logit_max_abs_diff"]["value"] > \
         c["logit_max_abs_diff"]["limit"]

@@ -7,7 +7,7 @@ import os
 import numpy as np
 import pytest
 
-from bench import check, program, reference
+from bench import check, families
 from bench.traffic import generate
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -24,6 +24,8 @@ def load(name):
 def test_reference_equals_lax_int(name, seed):
     from repro.compile import compile_model
     cfg = load(name)
+    family = families.load(cfg)
+    reference, program = family.reference, family.program
     net = reference.build_net(cfg)
     w = reference.make_weights(net, seed)
     x = generate.image_pool(seed, 8, cfg["img"], cfg["in_channels"])
@@ -39,7 +41,9 @@ def test_reference_equals_lax_int(name, seed):
 
 
 def test_weights_are_seeded():
-    net = reference.build_net(load("resnet8"))
+    cfg = load("resnet8")
+    reference = families.load(cfg).reference
+    net = reference.build_net(cfg)
     a = reference.make_weights(net, 3)["stem"][0]
     b = reference.make_weights(net, 3)["stem"][0]
     c = reference.make_weights(net, 4)["stem"][0]
@@ -50,6 +54,7 @@ def test_weights_are_seeded():
 def test_activations_use_their_range():
     # the seeded weights keep the last map neither dead nor saturated
     cfg = load("resnet20")
+    reference = families.load(cfg).reference
     net = reference.build_net(cfg)
     w = reference.make_weights(net, 1)
     x = generate.image_pool(1, 8, cfg["img"], cfg["in_channels"])

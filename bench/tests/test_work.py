@@ -5,15 +5,20 @@ import os
 
 import pytest
 
-from bench import reference, work
+from bench import families, work
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 
-def net(name):
+def load(name):
     with open(os.path.join(ROOT, "bench", "configs", f"{name}.json")) as f:
-        return reference.build_net(json.load(f))
+        return json.load(f)
+
+
+def net(name):
+    cfg = load(name)
+    return families.load(cfg).reference.build_net(cfg)
 
 
 @pytest.mark.parametrize("name,macs", [("resnet20", 40_813_184),
@@ -28,12 +33,11 @@ def test_macs_match_hand_count(name, macs):
 
 @pytest.mark.parametrize("name", ["resnet20", "resnet8"])
 def test_macs_match_program_graph(name):
-    from bench import program
     from repro.compile.lowering import model_graph
-    with open(os.path.join(ROOT, "bench", "configs", f"{name}.json")) as f:
-        cfg = program.program_config(json.load(f))
+    cfg = load(name)
+    program = families.load(cfg).program
     macs = 0
-    for n in model_graph(cfg).nodes:
+    for n in model_graph(program.program_config(cfg)).nodes:
         a = n.attrs
         if n.op == "conv":
             macs += a["fh"] * a["fw"] * a["ich"] * a["och"] * a["oh"] * a["ow"]

@@ -9,7 +9,7 @@ A mix file holds only parameters::
                   "mean_off_s": ..., "off_rate_hz": ...},
      "queued_batches": 2,                                         (closed)
      "pool_images": 4096, "sample": 1024,
-     "server": {...}}                     (the engine; see program.py)
+     "server": {...}}          (the engine; see families/<family>/program.py)
 
 The arrival processes are copied from the program's
 ``repro.traffic.loadgen`` (``PoissonProcess``, ``OnOffProcess``), not

@@ -1,10 +1,22 @@
 """Operations and bytes one image needs, counted from the configuration's
-shapes (``reference.build_net``), not from the program's dataflow model.
+shapes (the family's ``reference.build_net``), not from the program's
+dataflow model.
 
-MACs: every conv, the 1x1 downsample convs and the fc (``k*k*cin*cout``
-per output pixel, ``fc_in*classes`` for the head).  One MAC is two
-operations.  Bytes, the least a forward must move: the float32 image in,
-the float32 logits out, and once per forward call every weight and bias.
+``count(net)`` names no family.  It reads only this contract, which every
+family's ``Net`` meets:
+
+* ``net.convs()``: every conv of the network, each with ``k`` (square
+  kernel side), ``cin``, ``cout``, ``hin`` (input side), ``stride`` and
+  ``hout`` (output side);
+* ``net.stem``: the first conv, whose ``hin`` and ``cin`` are the image's
+  side and channels;
+* ``net.fc_in`` and ``net.num_classes``: the fc head.
+
+MACs: every conv (``k*k*cin*cout`` per output pixel, the 1x1 downsample
+convs among them) and the fc (``fc_in*classes``).  Pooling layers count no
+MACs.  One MAC is two operations.  Bytes, the least a forward must move:
+the float32 image in, the float32 logits out, and once per forward call
+every s8 weight, s16 conv bias and float32 fc bias.
 """
 from __future__ import annotations
 
