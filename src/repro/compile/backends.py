@@ -15,7 +15,9 @@ Built-in backends, all lowering the SAME plan (``lowering.plan_model``):
 
   * ``pallas``  — the fused kernel pipeline: ``conv_stem`` + one
                   ``resblock_fused`` call per residual block (paper Fig. 13
-                  dataflow; feature maps touch HBM once per kernel boundary).
+                  dataflow; feature maps touch HBM once per kernel boundary);
+                  for the bottleneck family the strided stem with its max
+                  pool and one fused bottleneck call per block.
   * ``lax-int`` — the reference integer graph on ``jax.lax`` convs: identical
                   int32 accumulators and shift arithmetic, unfused dataflow.
                   Bit-exact with ``pallas`` by construction.
@@ -101,21 +103,39 @@ def _relu_requant(acc, c: QConvParams, out_spec=A_SPEC):
     return Q.requantize_shift(jnp.maximum(acc, 0), c.product_exp, out_spec)
 
 
-def _int_head(h_u8, fc, in_spec=A_SPEC):
+def _int_head(h_u8, fc, in_spec=A_SPEC, pool: str = "avg"):
     """Global average pool + classifier (the paper's host-side tail), shared
     by every integer backend.  The pool sum and the fc dot run in int32, so
     they are exact; one float scale and the float bias follow, element by
     element.  A logit therefore depends on its own row alone, whatever the
     batch shape: a float pool + dot is not, on TPU its precision and
-    reduction order change with the compiled shape."""
+    reduction order change with the compiled shape.
+
+    ``pool="avg"`` dots the per-channel sums and folds the 1/(h*w) into the
+    float scale.  ``pool="avg_u8"`` (the bottleneck head) first divides each
+    sum by h*w, rounding half up, back onto the u8 activation grid, so the
+    fc accumulator stays under 2**31 at any map size."""
     _, h, w, c = h_u8.shape
-    bound = h * w * in_spec.qmax * max(-fc.w_spec.qmin, fc.w_spec.qmax) * c
-    assert bound < 2 ** 31, f"int32 head accumulator can overflow ({bound})"
+    wmax = max(-fc.w_spec.qmin, fc.w_spec.qmax)
     pooled = jnp.sum(h_u8.astype(jnp.int32), axis=(1, 2))
+    if pool == "avg_u8":
+        pooled = (pooled + (h * w) // 2) // (h * w)
+        scale = in_spec.scale * fc.w_spec.scale
+        bound = in_spec.qmax * wmax * c
+    else:
+        scale = in_spec.scale * fc.w_spec.scale / (h * w)
+        bound = h * w * in_spec.qmax * wmax * c
+    assert bound < 2 ** 31, f"int32 head accumulator can overflow ({bound})"
     acc = jnp.dot(pooled, fc.wq.astype(jnp.int32),
                   preferred_element_type=jnp.int32)
-    scale = in_spec.scale * fc.w_spec.scale / (h * w)
     return acc.astype(jnp.float32) * scale + fc.b
+
+
+def _max_pool(h):
+    """3x3 stride-2 SAME max pool of a post-ReLU u8 map: the pad is 0,
+    which there equals -inf."""
+    return jax.lax.reduce_window(h, jnp.zeros((), h.dtype), jax.lax.max,
+                                 (1, 3, 3, 1), (1, 2, 2, 1), "SAME")
 
 
 # ---------------------------------------------------------------------------
@@ -351,24 +371,33 @@ class LaxIntBackend:
 
         def forward(images):
             xq = Q.quantize(images, params.stem.x_spec)
-            h = _relu_requant(_int_conv(xq, params.stem), params.stem,
-                              stem_out)
+            h = _relu_requant(_int_conv(xq, params.stem, plan.stem.stride),
+                              params.stem, stem_out)
+            if plan.stem.pool:
+                h = _max_pool(h)
             for task in plan.blocks:
                 blk = params.blocks[task.index]
                 out_spec = block_outs[task.index]
-                y = _relu_requant(_int_conv(h, blk.conv0, task.stride),
-                                  blk.conv0, blk.conv1.x_spec)
+                if task.bottleneck:
+                    y = _relu_requant(_int_conv(h, blk.conv0), blk.conv0,
+                                      blk.conv1.x_spec)
+                    y = _relu_requant(_int_conv(y, blk.conv1, task.stride),
+                                      blk.conv1, blk.conv2.x_spec)
+                else:
+                    y = _relu_requant(_int_conv(h, blk.conv0, task.stride),
+                                      blk.conv0, blk.conv1.x_spec)
                 sh = blk.shifts_for(out_spec.exp)["skip_shift"]
                 if task.has_ds:
                     skip_q = Q.shift_align(
                         _int_conv(h, blk.ds, task.stride), sh)
                 else:
                     skip_q = Q.shift_align(h, sh)
-                h = _relu_requant(
-                    _int_conv(y, blk.conv1, 1, acc_init=skip_q), blk.conv1,
-                    out_spec)
+                last = blk.last
+                h = _relu_requant(_int_conv(y, last, 1, acc_init=skip_q),
+                                  last, out_spec)
             return _int_head(h, params.fc,
-                             block_outs[-1] if block_outs else stem_out)
+                             block_outs[-1] if block_outs else stem_out,
+                             plan.head.pool)
 
         return forward
 
@@ -384,19 +413,22 @@ class PallasBackend:
     def lower(self, g, cfg, params) -> Callable:
         if lowering._is_lm_cfg(cfg):
             return lower_lm("pallas", g, cfg, params)
-        from repro.kernels.conv_stem.ops import conv_stem_op
-        from repro.kernels.resblock_fused.ops import resblock_fused_op
+        from repro.kernels.conv_stem.ops import (conv_stem_op,
+                                                 conv_stem_pool_op)
+        from repro.kernels.resblock_fused.ops import (bottleneck_fused_op,
+                                                      resblock_fused_op)
 
         plan = lowering.plan_model(g, params)
         stem_out, block_outs = activation_out_specs(params, A_SPEC)
+        stem_op = conv_stem_pool_op if plan.stem.pool else conv_stem_op
 
         def forward(images):
             with jax.named_scope("input_quantize"):
                 xq = Q.quantize(images, params.stem.x_spec)
             st = params.stem
-            h = conv_stem_op(xq, st.wq, st.bq,
-                             shift=stem_out.exp - st.product_exp,
-                             config=plan.stem.config, name="stem")
+            h = stem_op(xq, st.wq, st.bq,
+                        shift=stem_out.exp - st.product_exp,
+                        config=plan.stem.config, name="stem")
             for task in plan.blocks:
                 blk = params.blocks[task.index]
                 sh = blk.shifts_for(block_outs[task.index].exp)
@@ -404,14 +436,23 @@ class PallasBackend:
                 if task.has_ds:
                     wd = blk.ds.wq
                     bd = blk.ds.bq.astype(jnp.int32)
+                name = lowering.kernel_name(task, cfg.stage_blocks)
+                if task.bottleneck:
+                    h = bottleneck_fused_op(
+                        h, blk.conv0.wq, blk.conv0.bq.astype(jnp.int32),
+                        blk.conv1.wq, blk.conv1.bq.astype(jnp.int32),
+                        blk.conv2.wq, blk.conv2.bq.astype(jnp.int32),
+                        wd, bd, stride=task.stride, config=task.config,
+                        name=name, **sh)
+                    continue
                 h = resblock_fused_op(
                     h, blk.conv0.wq, blk.conv0.bq.astype(jnp.int32),
                     blk.conv1.wq, blk.conv1.bq.astype(jnp.int32),
                     wd, bd, stride=task.stride, config=task.config,
-                    name=lowering.kernel_name(task, cfg.blocks_per_stage),
-                    **sh)
+                    name=name, **sh)
             return _int_head(h, params.fc,
-                             block_outs[-1] if block_outs else stem_out)
+                             block_outs[-1] if block_outs else stem_out,
+                             plan.head.pool)
 
         return forward
 
@@ -444,6 +485,10 @@ class PallasStreamBackend:
             # no LM megakernel exists; degrade gracefully to the per-task
             # pallas kernels (the singleton-chain fallback, graph-wide)
             return lower_lm("pallas", g, cfg, params)
+        if lowering.is_bottleneck_cfg(cfg):
+            raise lowering.LoweringError(
+                f"backend 'pallas-stream' has no bottleneck chain kernel for "
+                f"{cfg.name!r}; use 'pallas' or 'lax-int'")
         from repro.core import dataflow
         from repro.kernels.conv_stem.ops import conv_stem_op
         from repro.kernels.megakernel.megakernel import ChainBlockSpec
@@ -474,7 +519,7 @@ class PallasStreamBackend:
         stem_out, block_outs = activation_out_specs(params, A_SPEC)
         st = params.stem
         stem_shift = stem_out.exp - st.product_exp
-        bps = cfg.blocks_per_stage
+        stage_blocks = cfg.stage_blocks
 
         # static per-chain schedule: (operand pytree, ChainBlockSpec tuple)
         lowered = []
@@ -512,7 +557,7 @@ class PallasStreamBackend:
                         h, blk.conv0.wq, blk.conv0.bq.astype(jnp.int32),
                         blk.conv1.wq, blk.conv1.bq.astype(jnp.int32),
                         wd, bd, stride=task.stride, config=task.config,
-                        name=lowering.kernel_name(task, bps), **sh)
+                        name=lowering.kernel_name(task, stage_blocks), **sh)
                     continue
                 stem = (st.wq, st.bq.astype(jnp.int32)) \
                     if chain.stem is not None else None
@@ -520,7 +565,7 @@ class PallasStreamBackend:
                     h, ops, specs=specs, stem=stem,
                     stem_shift=stem_shift if chain.stem is not None else None,
                     config=chain_config(chain, images.shape[0]),
-                    name=lowering.kernel_name(chain, bps))
+                    name=lowering.kernel_name(chain, stage_blocks))
             return _int_head(h, params.fc,
                              block_outs[-1] if block_outs else stem_out)
 
@@ -541,6 +586,10 @@ class FloatBackend:
                 f"backend 'float' has no LM lowering for config "
                 f"{cfg.name!r} (family={cfg.family!r}); use 'pallas' or "
                 f"'lax-int'")
+        if lowering.is_bottleneck_cfg(cfg):
+            raise lowering.LoweringError(
+                f"backend 'float' has no bottleneck lowering for "
+                f"{cfg.name!r}; use 'pallas' or 'lax-int'")
         plan = lowering.plan_model(g, params)
         stem_out, block_outs = activation_out_specs(params, A_SPEC)
 

@@ -55,6 +55,8 @@ class StemTask:
     node: str                 # graph node name
     och: int
     config: Optional[KernelConfig] = None   # tuned tiling (None = default)
+    stride: int = 1
+    pool: Optional[str] = None  # "max": the 3x3/2 max pool that follows
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,6 +68,11 @@ class BlockTask:
     has_ds: bool              # 1x1 downsample merged into conv0 (loop_merge)
     och: int
     config: Optional[KernelConfig] = None   # tuned tiling (None = default)
+    conv2: Optional[str] = None  # a bottleneck's third conv (the add-fold)
+
+    @property
+    def bottleneck(self) -> bool:
+        return self.conv2 is not None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -193,6 +200,8 @@ class _WalkState:
     head_pool: Optional[str] = None
     head_fc: Optional[int] = None
     pending_conv0: Optional[G.Node] = None
+    pending_conv1: Optional[G.Node] = None    # a bottleneck's middle conv
+    bottlenecks: frozenset = frozenset()      # blocks that have a conv2
     # generic task program
     tasks: List[object] = dataclasses.field(default_factory=list)
     embed: Optional[G.Node] = None
@@ -202,7 +211,9 @@ class _WalkState:
 def _walk(g: G.Graph) -> _WalkState:
     """THE generic lowering driver: topological sort, then registry
     dispatch per node.  Unregistered kinds fail loudly with the node id."""
-    state = _WalkState(g=g)
+    state = _WalkState(g=g, bottlenecks=frozenset(
+        n.attrs.get("block") for n in g.nodes
+        if n.op == "conv" and n.attrs.get("role") == "conv2"))
     for n in G.topological_sort(g):
         handler = TASK_HANDLERS.get(n.op)
         if handler is None:
@@ -221,15 +232,17 @@ def _lower_noop(n: G.Node, state: _WalkState) -> None:
 
 @register_task("conv")
 def _lower_conv(n: G.Node, state: _WalkState) -> None:
-    """The conv pipeline's stateful pairing walk (stem, conv0/conv1 pairs),
-    exactly the pre-registry semantics."""
+    """The conv pipeline's stateful walk: the stem, then per residual block
+    conv0, conv1 and, in a bottleneck, conv2; the last conv of a block
+    carries the folded residual add."""
     role = n.attrs.get("role")
     if role == "stem":
         if not {"bn", "relu"} <= set(n.fused):
             raise _node_err(n, "stem conv must have bn+relu folded in "
                                "(fold_bn/merge_relu did not run)")
         state.stem = StemTask(node=n.name, och=n.attrs["och"],
-                              config=n.attrs.get("kcfg"))
+                              config=n.attrs.get("kcfg"),
+                              stride=n.attrs["stride"])
     elif role == "conv0":
         if state.pending_conv0 is not None:
             raise _node_err(
@@ -239,23 +252,35 @@ def _lower_conv(n: G.Node, state: _WalkState) -> None:
             raise _node_err(n, "conv0 emits no skip stream — "
                                "loop_merge/temporal_reuse did not run")
         state.pending_conv0 = n
-    elif role == "conv1":
+    elif role == "conv1" and n.attrs["block"] in state.bottlenecks:
+        # a bottleneck's middle conv: its conv2 closes the block
         c0 = state.pending_conv0
         if c0 is None or c0.attrs["block"] != n.attrs["block"]:
             raise _node_err(n, "conv1 without its conv0 (pairing check)")
+        state.pending_conv1 = n
+    elif role in ("conv1", "conv2"):
+        c0 = state.pending_conv0
+        mid = state.pending_conv1
+        if c0 is None or c0.attrs["block"] != n.attrs["block"] or \
+                (role == "conv2") != (mid is not None):
+            raise _node_err(n, f"{role} without its conv0 (pairing check)")
         if n.skip_in is None or "add_fold" not in n.fused:
-            raise _node_err(n, "residual add not folded into conv1 "
-                               "(add_fold did not run)")
+            raise _node_err(n, f"residual add not folded into {role} "
+                               f"(add_fold did not run)")
         if n.skip_in not in c0.outputs[1:]:
             raise _node_err(
                 n, f"skip input {n.skip_in!r} is not conv0's forwarded "
                    f"stream {c0.outputs[1:]}")
+        # the stride sits on conv0 of a basic block, on the 3x3 (v1.5) of
+        # a bottleneck
+        strided = c0 if mid is None else mid
         state.blocks.append(BlockTask(
-            index=n.attrs["block"], conv0=c0.name, conv1=n.name,
-            stride=c0.attrs["stride"],
+            index=n.attrs["block"], conv0=c0.name,
+            conv1=(mid or n).name, stride=strided.attrs["stride"],
             has_ds=any(f.startswith("downsample:") for f in c0.fused),
-            och=n.attrs["och"], config=c0.attrs.get("kcfg")))
-        state.pending_conv0 = None
+            och=n.attrs["och"], config=c0.attrs.get("kcfg"),
+            conv2=n.name if mid is not None else None))
+        state.pending_conv0 = state.pending_conv1 = None
     elif role == "ds":
         raise _node_err(n, "standalone downsample conv survived — "
                            "loop_merge did not run")
@@ -265,7 +290,15 @@ def _lower_conv(n: G.Node, state: _WalkState) -> None:
 
 @register_task("pool")
 def _lower_pool(n: G.Node, state: _WalkState) -> None:
-    state.head_pool = n.attrs.get("kind", "avg")
+    kind = n.attrs.get("kind", "avg")
+    if kind == "max":
+        # the stem's max pool: part of the stem task
+        if state.stem is None or state.blocks or state.stem.pool:
+            raise _node_err(n, "a max pool lowers only directly after the "
+                               "stem conv")
+        state.stem = dataclasses.replace(state.stem, pool=kind)
+    else:
+        state.head_pool = kind
 
 
 @register_task("linear")
@@ -328,6 +361,12 @@ def _lower_unembed(n: G.Node, state: _WalkState) -> None:
 # ---------------------------------------------------------------------------
 
 
+def is_bottleneck_cfg(cfg) -> bool:
+    """An ImageNet bottleneck config (``models.resnet.BottleneckConfig``)."""
+    from repro.models.resnet import BottleneckConfig
+    return isinstance(cfg, BottleneckConfig)
+
+
 def _is_lm_cfg(cfg) -> bool:
     return hasattr(cfg, "seq_len") and getattr(cfg, "family", None) in (
         "dense", "ssm")
@@ -341,6 +380,10 @@ def model_graph(cfg) -> G.Graph:
         if cfg.family == "dense":
             return G.build_transformer_graph(cfg, cfg.seq_len)
         return G.build_ssm_graph(cfg, cfg.seq_len)
+    if is_bottleneck_cfg(cfg):
+        return G.build_bottleneck_graph(cfg.stage_blocks, cfg.base_width,
+                                        cfg.expansion, cfg.img,
+                                        cfg.num_classes)
     return G.build_resnet_graph(cfg.blocks_per_stage, cfg.base_width,
                                 cfg.img, cfg.num_classes)
 
@@ -416,6 +459,9 @@ def plan_model(g: G.Graph, params: Optional[QResNetParams] = None) -> LoweringPl
             "(not a lowered conv graph?)")
     if state.pending_conv0 is not None:
         raise _node_err(state.pending_conv0, "unpaired conv0 at end of walk")
+    if state.pending_conv1 is not None:
+        raise _node_err(state.pending_conv1, "conv1 without its conv2 at "
+                                             "end of walk")
 
     plan = LoweringPlan(stem=state.stem, blocks=state.blocks,
                         head=HeadTask(pool=state.head_pool,
@@ -432,6 +478,11 @@ def plan_model(g: G.Graph, params: Optional[QResNetParams] = None) -> LoweringPl
                     f"block {t.index} (node {t.conv0!r}): graph "
                     f"downsample={t.has_ds} but params "
                     f"downsample={params.blocks[t.index].has_ds}")
+            if (params.blocks[t.index].conv2 is not None) != t.bottleneck:
+                raise LoweringError(
+                    f"block {t.index} (node {t.conv0!r}): graph "
+                    f"bottleneck={t.bottleneck} but params conv2="
+                    f"{params.blocks[t.index].conv2 is not None}")
     return plan
 
 
@@ -475,20 +526,26 @@ def plan_lm(g: G.Graph, params=None) -> LMPlan:
                   seq_len=state.embed.attrs["seq_len"])
 
 
-def kernel_name(task, blocks_per_stage: int) -> str:
+def kernel_name(task, stage_blocks) -> str:
     """The device-op name of a task's kernel: its place in the network, so
     a profile finds it across compiles and refactors.  ``stem``;
     ``s<stage>b<block>`` for a residual block (stages from 1, blocks from
     0), with ``_ds`` when its 1x1 downsample is fused; ``[stem_]<first>``
-    or ``[stem_]<first>_to_<last>`` for a chain."""
+    or ``[stem_]<first>_to_<last>`` for a chain.  ``stage_blocks`` is the
+    number of blocks in each stage (``cfg.stage_blocks``)."""
     if isinstance(task, StemTask):
         return "stem"
     if isinstance(task, ChainTask):
-        names = [kernel_name(t, blocks_per_stage) for t in task.blocks]
+        names = [kernel_name(t, stage_blocks) for t in task.blocks]
         name = names[0] if len(names) == 1 else f"{names[0]}_to_{names[-1]}"
         return name if task.stem is None else f"stem_{name}"
-    stage, block = divmod(task.index, blocks_per_stage)
-    return f"s{stage + 1}b{block}" + ("_ds" if task.has_ds else "")
+    block = task.index
+    for stage, n in enumerate(stage_blocks):
+        if block < n:
+            return f"s{stage + 1}b{block}" + ("_ds" if task.has_ds else "")
+        block -= n
+    raise LoweringError(f"block {task.index} is past the last stage of "
+                        f"{tuple(stage_blocks)}")
 
 
 def plan_chains(plan: LoweringPlan, cfg, cuts=None, fuse_stem: bool = True,

@@ -11,7 +11,8 @@ same data a typed spine the compiler can walk:
                         quantization grid recompiles while a change of weights
                         does not.
   * ``QLinearParams`` — the final classifier (int8 weights, float bias).
-  * ``QBlockParams``  — one residual block: conv0, conv1, optional downsample.
+  * ``QBlockParams``  — one residual block: conv0, conv1, optional
+                        downsample, and conv2 for a bottleneck block.
   * ``QResNetParams`` — the whole network; ``from_dict``/``to_dict`` adapt the
                         legacy dict layout both ways (bit-identical arrays).
 
@@ -104,15 +105,23 @@ class QLinearParams:
 @dataclasses.dataclass(frozen=True)
 class QBlockParams:
     """One residual block after graph optimization: two fused conv tasks and,
-    for stage-entry blocks, the 1x1 downsample merged into conv0's task."""
+    for stage-entry blocks, the 1x1 downsample merged into conv0's task.  A
+    bottleneck block has a third conv, ``conv2`` (1x1), and the skip folds
+    into its accumulator instead of conv1's."""
 
     conv0: QConvParams
     conv1: QConvParams
     ds: Optional[QConvParams] = None
+    conv2: Optional[QConvParams] = None
 
     @property
     def has_ds(self) -> bool:
         return self.ds is not None
+
+    @property
+    def last(self) -> QConvParams:
+        """The conv whose accumulator the skip stream initializes."""
+        return self.conv1 if self.conv2 is None else self.conv2
 
     def shifts(self, a_exp: int) -> dict:
         """Fixed-grid variant of :meth:`shifts_for` — every activation on
@@ -142,17 +151,26 @@ class QBlockParams:
             -> conv1's product domain (the add-fold accumulator init).
 
         With the legacy fixed-grid params (every activation on ``A_SPEC``)
-        this equals ``shifts(A_SPEC.exp)`` exactly."""
-        out = dict(shift0=self.conv1.x_spec.exp - self.conv0.product_exp,
-                   shift1=out_exp - self.conv1.product_exp)
-        if self.ds is not None:
-            out["skip_shift"] = self.ds.product_exp - self.conv1.product_exp
+        this equals ``shifts(A_SPEC.exp)`` exactly.
+
+        A bottleneck block adds ``shift2`` (conv2's product domain -> the
+        block output grid); ``shift1`` then lands on conv2's input grid and
+        the skip aligns into conv2's product domain."""
+        last = self.last
+        out = dict(shift0=self.conv1.x_spec.exp - self.conv0.product_exp)
+        if self.conv2 is None:
+            out["shift1"] = out_exp - self.conv1.product_exp
         else:
-            out["skip_shift"] = self.conv0.x_spec.exp - self.conv1.product_exp
+            out["shift1"] = self.conv2.x_spec.exp - self.conv1.product_exp
+            out["shift2"] = out_exp - self.conv2.product_exp
+        if self.ds is not None:
+            out["skip_shift"] = self.ds.product_exp - last.product_exp
+        else:
+            out["skip_shift"] = self.conv0.x_spec.exp - last.product_exp
         return out
 
     def tree_flatten(self):
-        return (self.conv0, self.conv1, self.ds), None
+        return (self.conv0, self.conv1, self.ds, self.conv2), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -161,14 +179,16 @@ class QBlockParams:
 
     @classmethod
     def from_dict(cls, d: dict) -> "QBlockParams":
+        opt = {k: QConvParams.from_dict(d[k]) for k in ("ds", "conv2")
+               if k in d}
         return cls(conv0=QConvParams.from_dict(d["conv0"]),
-                   conv1=QConvParams.from_dict(d["conv1"]),
-                   ds=QConvParams.from_dict(d["ds"]) if "ds" in d else None)
+                   conv1=QConvParams.from_dict(d["conv1"]), **opt)
 
     def to_dict(self) -> dict:
         out = dict(conv0=self.conv0.to_dict(), conv1=self.conv1.to_dict())
-        if self.ds is not None:
-            out["ds"] = self.ds.to_dict()
+        for k in ("ds", "conv2"):
+            if getattr(self, k) is not None:
+                out[k] = getattr(self, k).to_dict()
         return out
 
 

@@ -400,6 +400,93 @@ def resnet_block_shapes(blocks_per_stage: int, base: int = 16, img: int = 32
 
 
 # ---------------------------------------------------------------------------
+# TPU adaptation: the ImageNet bottleneck family (ResNet-50 v1.5) — the
+# fused bottleneck kernel and the strided stem with its max pool.  Unlike
+# the CIFAR kernels, which run inside Mosaic's default scoped-VMEM limit,
+# these set their limit per call from the models below.
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class BottleneckShape:
+    """One bottleneck block: input ``h x w x ich``, 1x1 to ``mid``, 3x3 at
+    ``stride``, 1x1 to ``och``, with a 1x1 projection (at ``stride``) on
+    the skip when ``downsample``."""
+    h: int
+    w: int
+    ich: int
+    mid: int
+    och: int
+    stride: int = 1
+    downsample: bool = False
+
+    @property
+    def oh(self) -> int:
+        return self.h // self.stride
+
+    @property
+    def ow(self) -> int:
+        return self.w // self.stride
+
+
+def bottleneck_task_vmem_bytes(b: BottleneckShape, batch_tile: int) -> int:
+    """Per-grid-step VMEM footprint of the fused bottleneck kernel: the
+    double-buffered u8 input and output tiles and filters (constant-index
+    blocks, two buffers each), and ``batch_tile`` images' working set with
+    every intermediate counted as live at once: the input widened to int32
+    with its s8 operand rows, y0 and its SAME-padded copy (and the int32
+    staging copy a strided 3x3 loads from), one tap's operand rows, conv1's
+    accumulator and y1, the staged input of a strided projection, the
+    projection's sum, the aligned skip and conv2's accumulator.  An upper
+    bound of what the compiler allocates, checked against it at every
+    ResNet-50 block shape by tests/test_tpu_compile.py."""
+    bt = batch_tile
+    tiles = 2 * (tiled_bytes((bt, b.h, b.w, b.ich), 1)
+                 + tiled_bytes((bt, b.oh, b.ow, b.och), 1))
+    filters = [(1, 1, b.ich, b.mid), (3, 3, b.mid, b.mid),
+               (1, 1, b.mid, b.och)] + \
+        ([(1, 1, b.ich, b.och)] if b.downsample else [])
+    wts = sum(_filter_vmem_bytes(*f) for f in filters)
+    hw, ohw = b.h * b.w, b.oh * b.ow
+    lo = 1 if b.stride == 1 else 0
+    ypad = tiled_bytes((b.h + lo + 1, b.w + lo + 1, b.mid), 4)
+    per_img = (tiled_bytes((b.h, b.w, b.ich), 4)             # x as int32
+               + tiled_bytes((hw, b.ich), 1)                  # conv0 rows
+               + tiled_bytes((b.h, b.w, b.mid), 4)            # y0
+               + ypad * (1 if b.stride == 1 else 2)           # padded y0
+               + tiled_bytes((ohw, b.mid), 4)                 # tap rows
+               + tiled_bytes((ohw, b.mid), 1)
+               + 2 * tiled_bytes((b.oh, b.ow, b.mid), 4)      # acc1, y1
+               + tiled_bytes((ohw, b.mid), 1)                 # conv2 rows
+               + 3 * tiled_bytes((b.oh, b.ow, b.och), 4))     # skip, acc2
+    if b.downsample:
+        per_img += tiled_bytes((b.oh, b.ow, b.och), 4)        # projection
+        if b.stride != 1:
+            per_img += tiled_bytes((b.h, b.w, b.ich), 4)      # staged x
+    return tiles + wts + bt * per_img
+
+
+def stem_pool_task_vmem_bytes(img: int, cin: int, och: int, k: int,
+                              batch_tile: int) -> int:
+    """Per-grid-step VMEM footprint of the strided stem with its max pool:
+    the double-buffered u8 patch tile (four output phases of
+    ``(img//4)**2`` rows, ``k*k*cin`` wide) and output tile, the filter,
+    and per image the patch rows widened to int32 with their s8 operand,
+    the four int32 phase maps with their padded copies, and the pooled
+    max."""
+    q = img // 4
+    kk = k * k * cin
+    bt = batch_tile
+    tiles = 2 * (tiled_bytes((bt, 4, q * q, kk), 1)
+                 + tiled_bytes((bt, q, q, och), 1))
+    per_img = (tiled_bytes((q * q, kk), 4) + tiled_bytes((q * q, kk), 1)
+               + 4 * tiled_bytes((q, q, och), 4)              # phase maps
+               + 4 * tiled_bytes((q + 1, q + 1, och), 4)      # padded
+               + 2 * tiled_bytes((q, q, och), 4))             # max, acc
+    return tiles + _filter_vmem_bytes(1, 1, kk, och) + bt * per_img
+
+
+# ---------------------------------------------------------------------------
 # TPU adaptation: LM task kinds (matmul / attention / scan) — the byte model
 # behind tune.space legality pruning and obs.profile rooflines for the
 # generic compiler's transformer / SSM task programs.  Same conventions as

@@ -11,11 +11,12 @@ it before code generation.  We reproduce that stage as a small, testable IR:
     4. ``temporal_reuse`` — residual block WITHOUT downsample: forward the
                             skip stream out of conv0's window buffer (Fig. 12a)
     5. ``add_fold``       — delete the Add node; the skip stream initializes
-                            conv1's accumulator (Fig. 13)
+                            the accumulator of the long branch's last conv
+                            (conv1, or conv2 of a bottleneck) (Fig. 13)
 
-After passes 3-5 every residual block is two fused tasks whose skip buffering
-is ``B_sc = B_1`` (eq. 22) instead of the receptive-field bound (eq. 21) —
-a 2x reduction (eq. 23), asserted in tests/test_graph.py.
+After passes 3-5 every basic residual block is two fused tasks whose skip
+buffering is ``B_sc = B_1`` (eq. 22) instead of the receptive-field bound
+(eq. 21) — a 2x reduction (eq. 23), asserted in tests/test_graph.py.
 
 On TPU the rewritten graph is what ``kernels/resblock_fused`` executes and what
 ``models/resnet.py`` mirrors at the jnp level (skip value initializes the
@@ -158,15 +159,36 @@ def merge_relu(g: Graph) -> Graph:
 @dataclasses.dataclass
 class ResidualBlock:
     producer: Node            # node whose output tensor feeds both branches
-    conv0: Node
-    conv1: Node
+    conv0: Node               # first conv of the long branch
+    conv1: Node               # last conv of the long branch: the add folds in
     add: Node
     downsample: Optional[Node]  # pointwise conv on the short branch, if any
+    mid: Tuple[Node, ...] = ()  # convs between conv0 and conv1 (bottleneck)
+
+
+# a long branch is two convs (basic block) or three (bottleneck)
+_MAX_LONG_BRANCH = 3
+
+
+def _long_branch(prod, cons, last: Node, done) -> Optional[List[Node]]:
+    """The conv chain ending at ``last``, walked back through convs whose
+    output feeds only the next conv, until ``done(first conv)`` holds;
+    None if no chain of 2 to 3 convs gets there."""
+    chain = [last]
+    while not done(chain[0]):
+        t = chain[0].inputs[0]
+        p = prod.get(t)
+        if p is None or p.op != "conv" or len(cons.get(t, ())) != 1 or \
+                len(chain) == _MAX_LONG_BRANCH:
+            return None
+        chain.insert(0, p)
+    return chain if len(chain) >= 2 else None
 
 
 def find_residual_blocks(g: Graph) -> List[ResidualBlock]:
     """A residual block = a tensor consumed by (a) a long branch conv chain of
-    length 2 and (b) either the Add directly or a pointwise conv then the Add."""
+    length 2 or 3 and (b) either the Add directly or a pointwise conv then
+    the Add."""
     cons = g.consumers()
     prod = g.producers()
     blocks = []
@@ -177,31 +199,38 @@ def find_residual_blocks(g: Graph) -> List[ResidualBlock]:
         pa, pb = prod.get(a), prod.get(b)
         if pa is None or pb is None:
             continue
-        # identify long branch: conv1 whose input comes from conv0
-        for long_end, short_end in ((pa, pb), (pb, pa)):
+        for long_end, short_end, t_short in ((pa, pb, b), (pb, pa, a)):
             if long_end.op != "conv":
                 continue
-            conv0 = prod.get(long_end.inputs[0])
-            if conv0 is None or conv0.op != "conv":
-                continue
-            src_tensor = conv0.inputs[0]
             # post-rewrite form (after loop_merge/temporal_reuse): the skip
             # stream is emitted by conv0 itself as a secondary output
-            t_short = a if short_end is pa else b
-            if short_end is conv0 and conv0.skip_out and \
-                    t_short in conv0.outputs[1:]:
-                blocks.append(ResidualBlock(conv0, conv0, long_end, n, None))
-                break
-            # short branch: either src_tensor directly, or pointwise conv of it
-            if short_end.outputs and short_end.op == "conv" and \
-                    short_end.inputs[0] == src_tensor and \
-                    short_end.attrs.get("fh", 1) == 1 and short_end.attrs.get("fw", 1) == 1:
-                blocks.append(ResidualBlock(prod.get(src_tensor) or conv0, conv0,
-                                            long_end, n, short_end))
-                break
-            if short_end is prod.get(src_tensor) or (
-                    short_end.outputs and src_tensor in short_end.outputs):
-                blocks.append(ResidualBlock(short_end, conv0, long_end, n, None))
+            if short_end.op == "conv" and short_end.skip_out and \
+                    t_short in short_end.outputs[1:]:
+                chain = _long_branch(prod, cons, long_end,
+                                     lambda c: c is short_end)
+                if chain:
+                    blocks.append(ResidualBlock(
+                        short_end, chain[0], chain[-1], n, None,
+                        tuple(chain[1:-1])))
+                    break
+            # short branch: a pointwise conv of the block input
+            if short_end.op == "conv" and \
+                    short_end.attrs.get("fh", 1) == 1 and \
+                    short_end.attrs.get("fw", 1) == 1:
+                src = short_end.inputs[0]
+                chain = _long_branch(prod, cons, long_end,
+                                     lambda c: c.inputs[0] == src)
+                if chain:
+                    blocks.append(ResidualBlock(
+                        prod.get(src) or chain[0], chain[0], chain[-1], n,
+                        short_end, tuple(chain[1:-1])))
+                    break
+            # short branch: the block input itself
+            chain = _long_branch(prod, cons, long_end,
+                                 lambda c: c.inputs[0] == t_short)
+            if chain:
+                blocks.append(ResidualBlock(short_end, chain[0], chain[-1], n,
+                                            None, tuple(chain[1:-1])))
                 break
     return blocks
 
@@ -295,15 +324,15 @@ def skip_buffer_report(g_before: Graph, g_after: Graph) -> List[dict]:
 
 
 # ---------------------------------------------------------------------------
-# ResNet graph builders (for tests/benchmarks; mirrors models/resnet.py)
+# ResNet graph builders (mirror models/resnet.py)
 # ---------------------------------------------------------------------------
 
 
 def _conv(name, tin, tout, ich, och, iw, ih, fh=3, fw=3, stride=1,
           role=None, block=None):
     """``role``/``block`` bind a conv node to its parameter container slot
-    (stem | conv0 | conv1 | ds, block index) — the handle ``repro.compile``'s
-    lowering uses to fetch weights for each fused task."""
+    (stem | conv0 | conv1 | conv2 | ds, block index) — the handle
+    ``repro.compile``'s lowering uses to fetch weights for each fused task."""
     return Node(name, "conv", [tin], [tout],
                 dict(ich=ich, och=och, iw=iw, ih=ih, fh=fh, fw=fw, stride=stride,
                      ow=iw // stride, oh=ih // stride, role=role, block=block))
@@ -347,6 +376,65 @@ def build_resnet_graph(num_blocks_per_stage: int, base_width: int = 16,
             idx += 1
     nodes.append(Node("pool", "pool", [tin], ["tp"],
                       dict(kind="avg", ih=res, iw=res, ich=ich)))
+    nodes.append(Node("fc", "linear", ["tp"], ["logits"],
+                      dict(din=ich, dout=num_classes, role="fc")))
+    nodes.append(Node("output", "output", ["logits"], []))
+    return Graph(nodes)
+
+
+def build_bottleneck_graph(stage_blocks=(3, 4, 6, 3), base_width: int = 64,
+                           expansion: int = 4, img: int = 224,
+                           num_classes: int = 1000) -> Graph:
+    """ImageNet bottleneck family (ResNet-50 v1.5: stage blocks 3/4/6/3):
+    7x7/2 stem, 3x3/2 max pool, then per block 1x1 -> 3x3 (the stage's
+    stride, v1.5) -> 1x1 at ``expansion`` times the width, with a 1x1
+    projection (at the stride) on every stage's first block.  The head
+    averages onto the u8 activation grid (``avg_u8``) before the fc."""
+    nodes = [Node("input", "input", ["%in"], ["t0"])]
+    nodes.append(_conv("stem", "t0", "t1", 3, base_width, img, img, fh=7,
+                       fw=7, stride=2, role="stem"))
+    nodes.append(Node("stem_bn", "bn", ["t1"], ["t1b"]))
+    nodes.append(Node("stem_relu", "relu", ["t1b"], ["t1r"]))
+    res = img // 2
+    nodes.append(Node("stem_pool", "pool", ["t1r"], ["t1p"],
+                      dict(kind="max", k=3, stride=2, ih=res, iw=res,
+                           ich=base_width)))
+    tin, ich, res, idx = "t1p", base_width, res // 2, 0
+    for stage, n_blocks in enumerate(stage_blocks):
+        mid = base_width * 2 ** stage
+        och = mid * expansion
+        for b in range(n_blocks):
+            stride = 2 if (stage > 0 and b == 0) else 1
+            ow = res // stride
+            t = f"s{stage}b{b}"
+            nodes.append(_conv(f"conv{idx}_0", tin, t + "c0", ich, mid, res,
+                               res, fh=1, fw=1, role="conv0", block=idx))
+            nodes.append(Node(f"bn{idx}_0", "bn", [t + "c0"], [t + "c0b"]))
+            nodes.append(Node(f"relu{idx}_0", "relu", [t + "c0b"],
+                              [t + "c0r"]))
+            nodes.append(_conv(f"conv{idx}_1", t + "c0r", t + "c1", mid, mid,
+                               res, res, stride=stride, role="conv1",
+                               block=idx))
+            nodes.append(Node(f"bn{idx}_1", "bn", [t + "c1"], [t + "c1b"]))
+            nodes.append(Node(f"relu{idx}_1", "relu", [t + "c1b"],
+                              [t + "c1r"]))
+            nodes.append(_conv(f"conv{idx}_2", t + "c1r", t + "c2", mid, och,
+                               ow, ow, fh=1, fw=1, role="conv2", block=idx))
+            nodes.append(Node(f"bn{idx}_2", "bn", [t + "c2"], [t + "c2b"]))
+            skip = tin
+            if b == 0:
+                nodes.append(_conv(f"ds{idx}", tin, t + "ds", ich, och, res,
+                                   res, fh=1, fw=1, stride=stride, role="ds",
+                                   block=idx))
+                skip = t + "ds"
+            nodes.append(Node(f"add{idx}", "add", [t + "c2b", skip],
+                              [t + "add"]))
+            nodes.append(Node(f"relu{idx}_a", "relu", [t + "add"],
+                              [t + "addr"]))
+            tin, ich, res = t + "addr", och, ow
+            idx += 1
+    nodes.append(Node("pool", "pool", [tin], ["tp"],
+                      dict(kind="avg_u8", ih=res, iw=res, ich=ich)))
     nodes.append(Node("fc", "linear", ["tp"], ["logits"],
                       dict(din=ich, dout=num_classes, role="fc")))
     nodes.append(Node("output", "output", ["logits"], []))

@@ -11,7 +11,10 @@ data.  So every conv kernel
   wrapper folds the ``128 Sum(w)`` term into the bias (:func:`offset_bias`),
   which also holds for the zero pad pixels;
 * reads stride-2 taps through strided loads of an int32 VMEM staging ref
-  (:func:`conv_taps`), never through a strided value slice.
+  (:func:`conv_taps`), never through a strided value slice.  Mosaic loads
+  with a stride only from a ref whose last dim is one lane tile, so maps
+  wider than 128 channels stage in 128-channel chunks
+  (:func:`stage_chunks`, :func:`strided_taps`).
 
 Interpret mode runs the same integer arithmetic, so the CPU conformance
 suite proves the chip's datapath bit-exact.
@@ -141,3 +144,39 @@ def block_body(xp, w0, b0, w1, b1, wd, bd, *, stride, shift0, shift1,
     # conv1 with add-fold: skip initializes the accumulator
     acc1 = conv_taps(pad_same(y0, 1), w1, skip + b1, 1, dot)
     return requant(acc1, shift1)
+
+
+def stage_chunks(stage, x):
+    """Write the int32 (bt, H, W, C) map ``x`` into ``stage``, an int32 VMEM
+    ref of shape (C // L, bt, H, W, L) with L = min(C, 128): chunk ``j``
+    holds channels ``j*L`` to ``(j+1)*L``."""
+    lanes = stage.shape[-1]
+    for j in range(stage.shape[0]):
+        stage[j] = x[..., j * lanes:(j + 1) * lanes]
+
+
+def stage_shape(bt: int, h: int, w: int, c: int):
+    """The (chunks, bt, h, w, lanes) shape :func:`stage_chunks` fills."""
+    lanes = min(c, 128)
+    assert c % lanes == 0, f"{c} channels do not split into 128-lane chunks"
+    return (c // lanes, bt, h, w, lanes)
+
+
+def strided_taps(stage, w, acc, stride: int, dot=dot_u8s8):
+    """:func:`conv_taps` at ``stride`` from a chunked staging ref
+    (:func:`stage_chunks`): each filter tap and channel chunk is one
+    ``(bt*oh*ow, L) x (L, Cout)`` dot accumulated into the int32 ``acc``
+    of shape (bt, oh, ow, Cout).  ``w`` may be a ref: only each tap's
+    chunk of it is loaded."""
+    bt, oh, ow, co = acc.shape
+    lanes = stage.shape[-1]
+    for kh in range(w.shape[0]):
+        for kw in range(w.shape[1]):
+            for j in range(stage.shape[0]):
+                xs = stage[j, :, pl.ds(kh, oh, stride=stride),
+                           pl.ds(kw, ow, stride=stride), :]
+                acc = acc + dot(
+                    xs.reshape(bt * oh * ow, lanes),
+                    w[kh, kw, j * lanes:(j + 1) * lanes, :]).reshape(
+                        bt, oh, ow, co)
+    return acc

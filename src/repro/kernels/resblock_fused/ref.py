@@ -32,3 +32,18 @@ def resblock_ref(x, w0, b0, w1, b1, wd=None, bd=None, *, stride=1,
         skip = shift_align(x, skip_shift)
     acc1 = _conv(y0, w1, b1) + skip
     return requant_u8(acc1, shift1)
+
+
+def bottleneck_ref(x, w0, b0, w1, b1, w2, b2, wd=None, bd=None, *, stride=1,
+                   shift0, shift1, shift2, skip_shift=0):
+    """The bottleneck block unfused: conv0 (1x1) -> relu/requant -> conv1
+    (3x3, ``stride``, lax SAME) -> relu/requant -> conv2 (1x1) + the skip
+    (block input, or the 1x1 projection at ``stride``) shifted onto its
+    product grid -> relu/requant.  x: (N,H,W,Cin) uint8 unpadded."""
+    y0 = requant_u8(_conv(x, w0, b0), shift0)
+    y1 = requant_u8(_conv(y0, w1, b1, stride), shift1)
+    if wd is not None:
+        skip = shift_align(_conv(x, wd, bd, stride), skip_shift)
+    else:
+        skip = shift_align(x.astype(jnp.int32), skip_shift)
+    return requant_u8(_conv(y1, w2, b2) + skip, shift2)

@@ -1,4 +1,5 @@
-"""ResNet8 / ResNet20 for CIFAR-10 — the paper's own networks (§IV).
+"""ResNet8 / ResNet20 for CIFAR-10 — the paper's own networks (§IV) — and
+the ImageNet bottleneck family (ResNet-50 v1.5) on the same integer scheme.
 
 Two execution paths share one parameter set:
 
@@ -17,7 +18,7 @@ conv1 of each block receives the skip stream as its accumulator init.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +41,28 @@ class ResNetConfig:
     quant: str = "qat"     # qat | none
     residual_fusion: bool = True
 
+    @property
+    def stage_blocks(self) -> Tuple[int, ...]:
+        """Blocks in each of the three stages."""
+        return (self.blocks_per_stage,) * 3
+
+
+@dataclasses.dataclass(frozen=True)
+class BottleneckConfig:
+    """The ImageNet bottleneck family (He et al. 2016, Table 1): a 7x7/2
+    stem to ``base_width`` channels and a 3x3/2 max pool, then stages of
+    bottleneck blocks (1x1 -> 3x3 -> 1x1, widths ``base_width * 2**stage``,
+    outputs ``expansion`` times wider), a 1x1 projection shortcut on every
+    stage's first block and the stride on its 3x3 conv (v1.5).  Only the
+    integer path serves it: ``compile_model`` with ``pallas`` or
+    ``lax-int``."""
+    name: str
+    stage_blocks: Tuple[int, ...] = (3, 4, 6, 3)
+    base_width: int = 64
+    expansion: int = 4
+    num_classes: int = 1000
+    img: int = 224
+
 
 def block_strides(cfg: "ResNetConfig") -> List[int]:
     out = []
@@ -51,6 +74,7 @@ def block_strides(cfg: "ResNetConfig") -> List[int]:
 
 RESNET8 = ResNetConfig("resnet8", blocks_per_stage=1)
 RESNET20 = ResNetConfig("resnet20", blocks_per_stage=3)
+RESNET50 = BottleneckConfig("resnet50")
 
 # static activation exponent grid: inputs in [0,1); post-ReLU activations are
 # unsigned 8-bit with exponent -5 (range [0,8)), pre-add signed -5.

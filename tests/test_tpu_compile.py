@@ -6,9 +6,12 @@ attached, so Mosaic's rules (MXU operand dtypes, strided loads, tiling,
 scoped VMEM) are checked here at no chip time.  The conv kernels compile at
 ResNet20's shapes (widths 16/32/64, 32x32 input, batch bucket 32), each at
 the largest batch tile the tuner admits and the chain megakernel at the
-tile the ``pallas-stream`` planner picks.  Each of them compiles with its
-scoped-VMEM limit set to what ``core.dataflow`` models for that call, which
-checks that the model is an upper bound of what the compiler allocates.
+tile the ``pallas-stream`` planner picks; ResNet-50's fused bottleneck
+kernel at each distinct block shape and its strided stem with the max
+pool, at batch bucket 256 and the tile the engine uses.  Each of them
+compiles with its scoped-VMEM limit set to what ``core.dataflow`` models
+for that call, which checks that the model is an upper bound of what the
+compiler allocates.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the test workers all import this
@@ -169,6 +172,70 @@ def test_block_chain_compiles_at_planned_tile_for_v5e(one_chip, vmem_limit):
                    tuple(tuple(_block_shapes(b)) for b in R20),
                    (((3, 3, 3, 16), i8), ((16,), i32)))
     assert chain.stem is not None and "tpu_custom_call" in hlo
+
+
+def _r50_blocks():
+    """ResNet-50's blocks in graph order, read off the conv nodes of the
+    graph the program lowers."""
+    convs = {}
+    for n in lowering.model_graph(R.RESNET50).nodes:
+        if n.op == "conv" and n.attrs["role"] != "stem":
+            convs.setdefault(n.attrs["block"], {})[n.attrs["role"]] = n.attrs
+    out = []
+    for _, c in sorted(convs.items()):
+        c0, c1, c2 = c["conv0"], c["conv1"], c["conv2"]
+        out.append(dataflow.BottleneckShape(
+            c0["ih"], c0["iw"], c0["ich"], c0["och"], c2["och"],
+            c1["stride"], downsample="ds" in c))
+    return out
+
+
+R50 = _r50_blocks()
+N50 = 256                                 # resnet50-offline's bucket
+
+
+def _r50_tile(cout: int) -> int:
+    """The batch tile the engine runs: the untuned default config."""
+    from repro.tune.config import DEFAULT
+    return DEFAULT.normalize(N50, cout).batch_tile
+
+
+@pytest.mark.parametrize("index", [0, 1, 3, 4, 7, 8, 13, 14],
+                         ids=["s1b0", "s1b1", "s2b0", "s2b1", "s3b0", "s3b1",
+                              "s4b0", "s4b1"])
+def test_bottleneck_compiles_for_v5e(one_chip, vmem_limit, index):
+    """Every distinct ResNet-50 block shape: stage entries with the fused
+    projection (stride 1 in stage 1, stride 2 after) and identity blocks,
+    within the VMEM ``core.dataflow`` counts for them."""
+    from repro.kernels.resblock_fused.bottleneck import bottleneck_fused
+    b = R50[index]
+    bt = _r50_tile(b.och)
+    vmem_limit(dataflow.bottleneck_task_vmem_bytes(b, bt))
+    shapes = [((N50, b.h, b.w, b.ich), u8), ((1, 1, b.ich, b.mid), i8),
+              ((b.mid,), i32), ((3, 3, b.mid, b.mid), i8), ((b.mid,), i32),
+              ((1, 1, b.mid, b.och), i8), ((b.och,), i32)]
+    if b.downsample:
+        shapes += [((1, 1, b.ich, b.och), i8), ((b.och,), i32)]
+    fn = partial(bottleneck_fused, stride=b.stride, shift0=7, shift1=7,
+                 shift2=7, skip_shift=-1 if b.downsample else 1,
+                 batch_tile=bt, interpret=False)
+    hlo = _compile(fn, one_chip, *shapes)
+    assert "tpu_custom_call" in hlo
+
+
+def test_stem_pool_compiles_for_v5e(one_chip, vmem_limit):
+    """ResNet-50's 7x7/2 stem from 3 to 64 channels with the 3x3/2 max pool
+    at 224x224."""
+    from repro.kernels.conv_stem.stem_pool import conv_stem_pool
+    c = R.RESNET50
+    bt = _r50_tile(c.base_width)
+    vmem_limit(dataflow.stem_pool_task_vmem_bytes(c.img, 3, c.base_width, 7,
+                                                  bt))
+    hlo = _compile(partial(conv_stem_pool, shift=7, batch_tile=bt,
+                           interpret=False), one_chip,
+                   ((N50, c.img, c.img, 3), u8), ((7, 7, 3, c.base_width), i8),
+                   ((c.base_width,), i32))
+    assert "tpu_custom_call" in hlo
 
 
 def test_matmul_int8_compiles_for_v5e(one_chip):
