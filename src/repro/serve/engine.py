@@ -37,6 +37,10 @@ from repro.serve import sched as S
 # shadow legitimately deviates and never counts as a mismatch.
 _INT_BACKENDS = frozenset({"pallas", "pallas-stream", "lax-int", "int"})
 
+# ResNetEngine's host staging buffers: a tick launches at most one batch
+# while another is in flight, so two batches' buffers are ever in use
+_STAGING_RING = 2
+
 
 @dataclasses.dataclass
 class Request:
@@ -218,6 +222,10 @@ class ResNetEngine:
         # (requests, images, logits) of the launched batches not yet
         # completed, oldest first: batch ids ticks, ticks + 1 (one ahead)
         self._in_flight: collections.deque = collections.deque()
+        # the host buffers batches are gathered into (each allocated on its
+        # first use) and the id of the batch that last filled each
+        self._staging: List[Optional[np.ndarray]] = [None] * _STAGING_RING
+        self._staging_bid = [-1] * _STAGING_RING
 
     def submit(self, req: ImageRequest):
         """Enqueue one request (shape-validated at admission)."""
@@ -286,23 +294,27 @@ class ResNetEngine:
         return True
 
     def _launch(self, ob) -> None:
-        """Take up to ``batch`` requests off the queue, stack them and
-        launch the model on them without waiting.  Under a live obs session
-        ``engine.gather`` (queue slice + stack) and the model's
-        ``model.put``/``model.launch`` carry the id of the batch launched,
-        and ``engine_overlapped_launches_total`` counts the launches made
-        while an earlier batch was still in flight."""
+        """Take up to ``batch`` requests off the queue, gather them into a
+        staging buffer (``_staging_buffer``) and launch the model on them
+        without waiting.  Under a live obs session ``engine.gather`` (queue
+        slice + gather) and the model's ``model.put``/``model.launch``
+        carry the id of the batch launched,
+        ``engine_overlapped_launches_total`` counts the launches made while
+        an earlier batch was still in flight, and
+        ``engine_staging_reuses_total`` those that refilled a staging
+        buffer an earlier batch had filled."""
         t0 = host_ns() if ob is not None else 0
+        bid = self.ticks + len(self._in_flight)
+        buf, reused = self._staging_buffer(bid)
         reqs = self.queue[:self.batch]
         del self.queue[:len(reqs)]
-        dtype = _input_contract(self.cfg)[1]
-        imgs = np.stack([np.asarray(r.image, dtype) for r in reqs])
+        imgs = np.stack([np.asarray(r.image, buf.dtype) for r in reqs],
+                        out=buf[:len(reqs)])
         if ob is not None:
             t_gather = host_ns()
             mark = ob.trace.host_mark()
         out = self.model(imgs)
         if ob is not None:
-            bid = self.ticks + len(self._in_flight)
             ob.trace.host_claim(mark, bid)    # the model's put and launch
             ob.trace.host_span("engine.gather", t0, t_gather, bid)
             if self._in_flight:
@@ -310,7 +322,41 @@ class ResNetEngine:
                     "engine_overlapped_launches_total",
                     "batches launched while an earlier batch was in "
                     "flight").inc()
+            if reused:
+                ob.metrics.counter(
+                    "engine_staging_reuses_total",
+                    "launches that filled an already-faulted staging "
+                    "buffer").inc()
         self._in_flight.append((reqs, imgs, out))
+
+    def _staging_buffer(self, bid: int):
+        """The host buffer batch ``bid`` is gathered into, and whether an
+        earlier batch filled it.
+
+        A fresh array of a whole batch (154 MB of f32 224x224 images at
+        256) is a new ``mmap`` whose every page faults on first write, so
+        batches are gathered into a ring of ``_STAGING_RING`` buffers of
+        the engine's batch shape, each written through once when it is
+        allocated.  A buffer is free once the batch that last filled it
+        has completed (its id is below ``ticks``): until then the device
+        may still read it and the shadows replay it.  The least recently
+        filled free buffer is taken, so the ring's buffers take turns; a
+        launch that finds none free raises rather than overwrite a batch
+        in flight."""
+        free = [i for i, b in enumerate(self._staging_bid) if b < self.ticks]
+        if not free:
+            raise RuntimeError(
+                f"batch {bid}: every staging buffer holds a batch in flight "
+                f"({self._staging_bid}, {self.ticks} completed)")
+        i = min(free, key=self._staging_bid.__getitem__)
+        buf = self._staging[i]
+        reused = buf is not None
+        if buf is None:
+            shape, dtype = _input_contract(self.cfg)
+            buf = self._staging[i] = np.empty((self.batch,) + shape, dtype)
+            buf.fill(0)                   # fault every page in now
+        self._staging_bid[i] = bid
+        return buf, reused
 
     def run(self, max_ticks: int = 10_000) -> int:
         """Tick until the queue and the in-flight batches are empty;

@@ -98,6 +98,25 @@ def test_resnet_engine_records_one_nest_per_batch(qp8):
     assert ob.metrics.total("engine_overlapped_launches_total") == 2
 
 
+def test_resnet_engine_refills_the_same_staging_buffers(qp8):
+    eng = ResNetEngine(R.RESNET8, qp8, batch=2, backend="lax-int")
+    ob = obsrt.instrument()
+    for r in _requests(11):                  # 6 launches, the last ragged
+        eng.submit(r)
+    rings = []
+    while eng.tick():
+        rings.append([id(b) for b in eng._staging])
+        for _, staged, _ in eng._in_flight:  # gathered into the ring
+            assert any(staged.base is b for b in eng._staging)
+    obsrt.disable()
+    assert eng.ticks == 6
+    assert all(b is not None for b in eng._staging)
+    assert rings == [rings[0]] * 6           # allocated once, then reused
+    assert ob.metrics.total("engine_staging_reuses_total") == \
+        6 - len(eng._staging)
+    assert ob.metrics.total("engine_overlapped_launches_total") == 5
+
+
 def test_sharded_engine_records_dispatch_and_harvest_nests(qp8):
     eng = ShardedResNetEngine(R.RESNET8, qp8, batch=2, backend="lax-int",
                               replicas=1, batch_sizes=(1, 2), slack_ms=0.0)

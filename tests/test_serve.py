@@ -201,6 +201,36 @@ def test_overlapped_launches_count_all_but_the_first_of_a_drained_run(
     assert ob.metrics.total("engine_overlapped_launches_total") == 4
 
 
+def test_staging_buffers_hold_each_batch_until_it_completes(qp8):
+    """Nine images in batches of 2: the two staging buffers are refilled
+    three times between them and the last batch is ragged.  A buffer
+    refilled while its batch is in flight, or before the shadow replays
+    it, would give a request another image's logits or the shadow a
+    deviation."""
+    imgs = np.asarray(jax.random.uniform(
+        jax.random.PRNGKey(3), (9, 32, 32, 3), minval=0.0, maxval=0.999))
+    eng = ResNetEngine(R.RESNET8, qp8, batch=2, backend="int",
+                       ab_backends=("int",))
+    reqs = _serve(eng, imgs)
+    for r, img in zip(reqs, imgs):
+        np.testing.assert_array_equal(
+            r.logits, np.asarray(eng.model(img[None]))[0])
+    assert eng.ab_stats["int"] == [0.0] * 5
+
+
+def test_a_launch_with_every_staging_buffer_in_flight_raises(qp8, images):
+    eng = ResNetEngine(R.RESNET8, qp8, batch=2, backend="lax-int")
+    for r in _requests(images):
+        eng.submit(r)
+    eng._launch(None)
+    eng._launch(None)                        # two batches in flight
+    with pytest.raises(RuntimeError, match="in flight"):
+        eng._launch(None)
+    assert len(eng.queue) == 2               # the queue is left as it was
+    for k, (_, staged, _) in enumerate(eng._in_flight):
+        np.testing.assert_array_equal(staged, images[2 * k:2 * k + 2])
+
+
 # ---------------------------------------------------------------------------
 # submit-time validation (regression: mixed image shapes crashed tick)
 # ---------------------------------------------------------------------------
