@@ -20,7 +20,7 @@ from __future__ import annotations
 import asyncio
 import collections
 import dataclasses
-from typing import List, Optional
+from typing import List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -36,10 +36,6 @@ from repro.serve import sched as S
 # error, and trips the health monitor's bit-exactness sentinel.  The float
 # shadow legitimately deviates and never counts as a mismatch.
 _INT_BACKENDS = frozenset({"pallas", "pallas-stream", "lax-int", "int"})
-
-# ResNetEngine's host staging buffers: a tick launches at most one batch
-# while another is in flight, so two batches' buffers are ever in use
-_STAGING_RING = 2
 
 
 @dataclasses.dataclass
@@ -143,25 +139,109 @@ class ImageRequest:
     done: bool = False
 
 
-def _input_contract(cfg):
-    """Per-request payload (shape, numpy dtype) of one config — the serving
-    mirror of ``CompiledModel.input_spec`` minus the batch dim: float images
-    for conv configs, int32 token vectors for LM configs."""
-    if hasattr(cfg, "seq_len"):
-        return (cfg.seq_len,), np.int32
-    return (cfg.img, cfg.img, 3), np.float32
-
-
-def _validate_image(cfg, req: ImageRequest) -> None:
-    """Every compiled executable is fixed-shape, so a mismatched payload can
-    never be batched; rejecting at submit keeps the tick loops total.
-    Shared by both engines so the input contract has one home."""
-    expect, _ = _input_contract(cfg)
-    shape = tuple(np.shape(req.image))
-    if shape != expect:
+def _bucket_sizes(batch: int, batch_sizes) -> tuple:
+    """An engine's buckets (default: its max batch alone), holding it."""
+    sizes = (batch,) if batch_sizes is None else batch_sizes
+    if batch not in sizes:
         raise ValueError(
-            f"request {req.rid}: payload shape {shape} does not match the "
-            f"compiled input shape {expect} for {cfg.name}")
+            f"max batch {batch} must be one of batch_sizes {sizes}")
+    return sizes
+
+
+class _Batch(NamedTuple):
+    """A launched batch: its requests, the staging buffer whose first
+    ``len(reqs)`` rows hold their images, and its logits on the device."""
+    reqs: List[ImageRequest]
+    buf: np.ndarray
+    out: jax.Array
+
+
+class _BatchPath:
+    """One device's batch path, under both image engines: gather, launch,
+    wait and fetch, fan out.  ``run`` launches a batch without waiting (on
+    the engine's model, or on one replica of its pool); ``spec``, the
+    model's ``input_spec`` at the engine's max batch, is the input contract.
+
+    A fresh array of a whole batch (154 MB of f32 224x224 images at 256) is
+    a new ``mmap`` whose every page faults on first write, so batches are
+    gathered into buffers of ``spec``'s shape, each faulted in once when
+    allocated.  A batch holds its buffer until its fan-out: the device may
+    read it until then (on the CPU ``jnp.asarray`` may alias it), and so
+    may ``ResNetEngine``'s shadows.  Freed buffers take turns on a FIFO
+    list; one is allocated only when none is free."""
+
+    def __init__(self, run, spec: jax.ShapeDtypeStruct):
+        self._run = run
+        self.shape, self.dtype = tuple(spec.shape), np.dtype(spec.dtype)
+        self._free: collections.deque = collections.deque()
+
+    def check(self, req: ImageRequest) -> None:
+        """Reject a payload no fixed-shape executable can batch, at submit,
+        so that the engines' loops stay total."""
+        shape = tuple(np.shape(req.image))
+        if shape != self.shape[1:]:
+            raise ValueError(
+                f"request {req.rid}: payload shape {shape} does not match "
+                f"the compiled input shape {self.shape[1:]}")
+
+    def launch(self, reqs: List[ImageRequest], bid: int, ob,
+               t_start: int) -> _Batch:
+        """Gather ``reqs`` into a staging buffer and launch them.  Under a
+        live obs session ``engine.gather`` (from the caller's ``t_start``)
+        and the model's ``model.put``/``model.launch`` carry ``bid``, and
+        ``engine_staging_reuses_total`` counts the launches that refilled
+        a buffer an earlier batch had filled."""
+        reused = bool(self._free)
+        if reused:
+            buf = self._free.popleft()
+        else:
+            buf = np.empty(self.shape, self.dtype)
+            buf.fill(0)                   # fault every page in now
+        imgs = np.stack([np.asarray(r.image, buf.dtype) for r in reqs],
+                        out=buf[:len(reqs)])
+        if ob is not None:
+            t_gather = host_ns()
+            mark = ob.trace.host_mark()
+        out = self._run(imgs)
+        if ob is not None:
+            ob.trace.host_claim(mark, bid)    # the model's put and launch
+            ob.trace.host_span("engine.gather", t_start, t_gather, bid)
+            if reused:
+                ob.metrics.counter(
+                    "engine_staging_reuses_total",
+                    "launches that filled an already-faulted staging "
+                    "buffer").inc()
+        return _Batch(reqs, buf, out)
+
+    def fetch(self, b: _Batch, bid: int, ob) -> np.ndarray:
+        """Wait for batch ``b``'s logits and read them back (``engine.wait``,
+        ``engine.fetch``, carrying ``bid``); a failed batch frees its buffer
+        before the error surfaces."""
+        t_wait = host_ns() if ob is not None else 0
+        try:
+            jax.block_until_ready(b.out)
+            t_ready = host_ns() if ob is not None else 0
+            logits = np.asarray(b.out)
+        except Exception:
+            self._free.append(b.buf)
+            raise
+        if ob is not None:
+            t_fetched, tr = host_ns(), ob.trace
+            tr.host_span("engine.wait", t_wait, t_ready, bid)
+            tr.host_span("engine.fetch", t_ready, t_fetched, bid)
+        return logits
+
+    def fanout(self, b: _Batch, logits: np.ndarray, bid: int, ob) -> None:
+        """Give each request its logits, label and done flag
+        (``engine.fanout``, carrying ``bid``), then free ``b``'s buffer."""
+        t0 = host_ns() if ob is not None else 0
+        for i, r in enumerate(b.reqs):
+            r.logits = logits[i]
+            r.label = int(np.argmax(logits[i]))
+            r.done = True
+        self._free.append(b.buf)
+        if ob is not None:
+            ob.trace.host_span("engine.fanout", t0, host_ns(), bid)
 
 
 class ResNetEngine:
@@ -193,17 +273,12 @@ class ResNetEngine:
     """
 
     def __init__(self, cfg, qparams, batch: int = 8, backend: str = "pallas",
-                 params=None, batch_sizes=None, ab_backends=(), tune=None):
+                 batch_sizes=None, ab_backends=(), tune=None):
         from repro.compile import compile_model
 
-        del params  # legacy arg; the float backend is now self-contained
         self.cfg, self.batch = cfg, batch
         self.backend = backend
-        if batch_sizes is None:
-            batch_sizes = (batch,)
-        if batch not in batch_sizes:
-            raise ValueError(
-                f"max batch {batch} must be one of batch_sizes {batch_sizes}")
+        batch_sizes = _bucket_sizes(batch, batch_sizes)
         # ``tune`` flows straight into compile_model: a per-task dict /
         # TuneResult from repro.tune, or "auto"/"analytic"/"device".  Tuning
         # only reschedules the kernels — logits are bit-identical — so the
@@ -219,17 +294,16 @@ class ResNetEngine:
         self.queue: List[ImageRequest] = []
         self.served = 0
         self.ticks = 0                    # batches served (the batch id)
-        # (requests, images, logits) of the launched batches not yet
-        # completed, oldest first: batch ids ticks, ticks + 1 (one ahead)
+        # runs ``self.model`` as it is at each launch, so a model swapped
+        # in after construction serves
+        self._path = _BatchPath(lambda imgs: self.model(imgs),
+                                self.model.input_spec(batch))
+        # launched, not completed, oldest first: ids ticks, ticks + 1
         self._in_flight: collections.deque = collections.deque()
-        # the host buffers batches are gathered into (each allocated on its
-        # first use) and the id of the batch that last filled each
-        self._staging: List[Optional[np.ndarray]] = [None] * _STAGING_RING
-        self._staging_bid = [-1] * _STAGING_RING
 
     def submit(self, req: ImageRequest):
         """Enqueue one request (shape-validated at admission)."""
-        _validate_image(self.cfg, req)
+        self._path.check(req)
         self.queue.append(req)
 
     def tick(self) -> bool:
@@ -256,12 +330,9 @@ class ResNetEngine:
             self._launch(ob)
         if self.queue:
             self._launch(ob)
-        reqs, imgs, out = self._in_flight.popleft()
-        t_wait = host_ns() if ob is not None else 0
-        jax.block_until_ready(out)
-        t_ready = host_ns() if ob is not None else 0
-        logits = np.asarray(out)
-        t_fetched = host_ns() if ob is not None else 0
+        b, bid = self._in_flight.popleft(), self.ticks
+        logits = self._path.fetch(b, bid, ob)
+        imgs = b.buf[:len(b.reqs)]
         for name, shadow in self.shadows.items():
             dev = np.max(np.abs(np.asarray(shadow(imgs)) - logits))
             self.ab_stats[name].append(float(dev))
@@ -278,85 +349,28 @@ class ResNetEngine:
                         "ab_mismatch_total",
                         "integer shadow disagreed bitwise with primary").inc(
                             shadow=name)
-        t_fanout = host_ns() if ob is not None else 0
-        for i, r in enumerate(reqs):
-            r.logits = logits[i]
-            r.label = int(np.argmax(logits[i]))
-            r.done = True
-        self.served += len(reqs)
+        self._path.fanout(b, logits, bid, ob)
+        self.served += len(b.reqs)
         if ob is not None:
-            t1, tr, bid = host_ns(), ob.trace, self.ticks
-            tr.host_span("engine.tick", t0, t1, bid)
-            tr.host_span("engine.wait", t_wait, t_ready, bid)
-            tr.host_span("engine.fetch", t_ready, t_fetched, bid)
-            tr.host_span("engine.fanout", t_fanout, t1, bid)
+            ob.trace.host_span("engine.tick", t0, host_ns(), bid)
         self.ticks += 1
         return True
 
     def _launch(self, ob) -> None:
-        """Take up to ``batch`` requests off the queue, gather them into a
-        staging buffer (``_staging_buffer``) and launch the model on them
-        without waiting.  Under a live obs session ``engine.gather`` (queue
-        slice + gather) and the model's ``model.put``/``model.launch``
-        carry the id of the batch launched,
-        ``engine_overlapped_launches_total`` counts the launches made while
-        an earlier batch was still in flight, and
-        ``engine_staging_reuses_total`` those that refilled a staging
-        buffer an earlier batch had filled."""
+        """Launch up to ``batch`` requests off the queue.  Under a live obs
+        session ``engine_overlapped_launches_total`` counts the launches
+        made while an earlier batch was still in flight."""
         t0 = host_ns() if ob is not None else 0
-        bid = self.ticks + len(self._in_flight)
-        buf, reused = self._staging_buffer(bid)
         reqs = self.queue[:self.batch]
         del self.queue[:len(reqs)]
-        imgs = np.stack([np.asarray(r.image, buf.dtype) for r in reqs],
-                        out=buf[:len(reqs)])
-        if ob is not None:
-            t_gather = host_ns()
-            mark = ob.trace.host_mark()
-        out = self.model(imgs)
-        if ob is not None:
-            ob.trace.host_claim(mark, bid)    # the model's put and launch
-            ob.trace.host_span("engine.gather", t0, t_gather, bid)
-            if self._in_flight:
-                ob.metrics.counter(
-                    "engine_overlapped_launches_total",
-                    "batches launched while an earlier batch was in "
-                    "flight").inc()
-            if reused:
-                ob.metrics.counter(
-                    "engine_staging_reuses_total",
-                    "launches that filled an already-faulted staging "
-                    "buffer").inc()
-        self._in_flight.append((reqs, imgs, out))
-
-    def _staging_buffer(self, bid: int):
-        """The host buffer batch ``bid`` is gathered into, and whether an
-        earlier batch filled it.
-
-        A fresh array of a whole batch (154 MB of f32 224x224 images at
-        256) is a new ``mmap`` whose every page faults on first write, so
-        batches are gathered into a ring of ``_STAGING_RING`` buffers of
-        the engine's batch shape, each written through once when it is
-        allocated.  A buffer is free once the batch that last filled it
-        has completed (its id is below ``ticks``): until then the device
-        may still read it and the shadows replay it.  The least recently
-        filled free buffer is taken, so the ring's buffers take turns; a
-        launch that finds none free raises rather than overwrite a batch
-        in flight."""
-        free = [i for i, b in enumerate(self._staging_bid) if b < self.ticks]
-        if not free:
-            raise RuntimeError(
-                f"batch {bid}: every staging buffer holds a batch in flight "
-                f"({self._staging_bid}, {self.ticks} completed)")
-        i = min(free, key=self._staging_bid.__getitem__)
-        buf = self._staging[i]
-        reused = buf is not None
-        if buf is None:
-            shape, dtype = _input_contract(self.cfg)
-            buf = self._staging[i] = np.empty((self.batch,) + shape, dtype)
-            buf.fill(0)                   # fault every page in now
-        self._staging_bid[i] = bid
-        return buf, reused
+        b = self._path.launch(reqs, self.ticks + len(self._in_flight), ob,
+                              t0)
+        if ob is not None and self._in_flight:
+            ob.metrics.counter(
+                "engine_overlapped_launches_total",
+                "batches launched while an earlier batch was in "
+                "flight").inc()
+        self._in_flight.append(b)
 
     def run(self, max_ticks: int = 10_000) -> int:
         """Tick until the queue and the in-flight batches are empty;
@@ -389,9 +403,10 @@ class ShardedResNetEngine:
           -> harvest (block on results, stamp completion, record queue-wait
              vs compute latency split)
 
-    Bit-exact with the single-device :class:`ResNetEngine` path: replication
-    and coalescing change *where and when* a batch runs, never the
-    arithmetic (asserted in tests/test_serve_sharded.py).
+    Each replica runs the batch path of the single-device
+    :class:`ResNetEngine` and is bit-exact with it: replication and
+    coalescing change *where and when* a batch runs, never the arithmetic
+    (asserted in tests/test_serve_sharded.py).
 
     ``clock`` is injectable (``sched.FakeClock``) so scheduling behavior is
     simulable; ``max_pending`` bounds admission (``submit`` raises
@@ -407,14 +422,10 @@ class ShardedResNetEngine:
 
         self.cfg, self.batch = cfg, batch
         self.backend = backend
-        if batch_sizes is None:
-            batch_sizes = (batch,)
-        if batch not in batch_sizes:
-            raise ValueError(
-                f"max batch {batch} must be one of batch_sizes {batch_sizes}")
         # lowered ONCE; the pool only adds per-device XLA compiles
         self.model = compile_model(cfg, qparams, backend=backend,
-                                   batch_sizes=batch_sizes, tune=tune)
+                                   batch_sizes=_bucket_sizes(
+                                       batch, batch_sizes), tune=tune)
         self.tuning = self.model.tuning
         self.pool = S.ReplicaPool(self.model, devices=devices,
                                   replicas=replicas)
@@ -424,7 +435,10 @@ class ShardedResNetEngine:
             service_estimate_s=service_estimate_ms * 1e-3)
         self.clock = self.sched.clock
         self.served = 0
-        self._in_flight: List[tuple] = []       # (Dispatch, logits array)
+        spec = self.model.input_spec(batch)
+        self._paths = [_BatchPath(lambda imgs, i=i: self.pool.run(i, imgs),
+                                  spec) for i in range(len(self.pool))]
+        self._in_flight: List[tuple] = []       # (Dispatch, _Batch)
 
     # -- admission ----------------------------------------------------------
 
@@ -433,7 +447,7 @@ class ShardedResNetEngine:
         """Admit one request.  ``deadline_ms`` is relative to now; omit it
         for best-effort coalescing under the ``slack_ms`` window.  Raises
         ``sched.Backpressure`` at ``max_pending``."""
-        _validate_image(self.cfg, req)
+        self._paths[0].check(req)             # one contract for every replica
         deadline_in = None if deadline_ms is None else deadline_ms * 1e-3
         return self.sched.submit(req, deadline_in=deadline_in,
                                  priority=priority)
@@ -479,20 +493,13 @@ class ShardedResNetEngine:
             if d is None:
                 break
             t_poll = host_ns() if ob is not None else 0
-            dtype = _input_contract(self.cfg)[1]
-            imgs = np.stack([np.asarray(r.payload.image, dtype)
-                             for r in d.requests])
-            if ob is not None:
-                t_gather = host_ns()
-                mark = ob.trace.host_mark()
-            out = self.pool.run(d.replica.index, imgs)   # async dispatch
-            self._in_flight.append((d, out))
+            b = self._paths[d.replica.index].launch(
+                [r.payload for r in d.requests], d.seq, ob, t_poll)
+            self._in_flight.append((d, b))
             if ob is not None:
                 tr = ob.trace
-                tr.host_claim(mark, d.seq)    # the model's put and launch
                 tr.host_span("engine.dispatch", t0, host_ns(), d.seq)
                 tr.host_span("sched.poll", t0, t_poll, d.seq)
-                tr.host_span("engine.gather", t_poll, t_gather, d.seq)
             n += 1
         return n
 
@@ -503,8 +510,8 @@ class ShardedResNetEngine:
         (inflating compute_ms and the deadline EWMA), and would hold the
         loop hostage to the slowest replica while due batches could be
         dispatching to idle ones."""
-        for i, (_, out) in enumerate(self._in_flight):
-            is_ready = getattr(out, "is_ready", None)
+        for i, (_, b) in enumerate(self._in_flight):
+            is_ready = getattr(b.out, "is_ready", None)
             if is_ready is not None and is_ready():
                 return i
         return None
@@ -527,36 +534,27 @@ class ShardedResNetEngine:
                 if not block or n > 0:
                     break         # let the caller dispatch more work first
                 i = 0             # nothing ready anywhere: wait on the oldest
-            d, out = self._in_flight[i]
-            t_wait = host_ns() if ob is not None else 0
+            d, b = self._in_flight.pop(i)
+            path = self._paths[d.replica.index]
             try:
-                jax.block_until_ready(out)
-                t_ready = host_ns() if ob is not None else 0
-                logits = np.asarray(out)
+                logits = path.fetch(b, d.seq, ob)
             except Exception:
                 # a dispatch whose device execution errored must not jam the
-                # head of the line or leak in-flight slots: evict it, release
-                # the scheduler accounting (its requests stay done=False so
-                # callers can see the failure), then surface the error
-                self._in_flight.pop(i)
+                # head of the line or leak in-flight slots: it is evicted;
+                # release the scheduler accounting (its requests stay
+                # done=False so callers can see the failure), then surface
+                # the error
                 self.sched.complete(d, failed=True)
                 raise
             t_fetched = host_ns() if ob is not None else 0
-            self._in_flight.pop(i)
             self.sched.complete(d)
             t_complete = host_ns() if ob is not None else 0
-            for j, r in enumerate(d.requests):
-                r.payload.logits = logits[j]
-                r.payload.label = int(np.argmax(logits[j]))
-                r.payload.done = True
+            path.fanout(b, logits, d.seq, ob)
             self.served += len(d)
             if ob is not None:
-                t1, tr = host_ns(), ob.trace
-                tr.host_span("engine.harvest", t0, t1, d.seq)
-                tr.host_span("engine.wait", t_wait, t_ready, d.seq)
-                tr.host_span("engine.fetch", t_ready, t_fetched, d.seq)
+                tr = ob.trace
+                tr.host_span("engine.harvest", t0, host_ns(), d.seq)
                 tr.host_span("sched.complete", t_fetched, t_complete, d.seq)
-                tr.host_span("engine.fanout", t_complete, t1, d.seq)
             n += 1
         return n
 

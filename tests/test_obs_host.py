@@ -103,18 +103,40 @@ def test_resnet_engine_refills_the_same_staging_buffers(qp8):
     ob = obsrt.instrument()
     for r in _requests(11):                  # 6 launches, the last ragged
         eng.submit(r)
-    rings = []
+    held = []                        # every buffer, free or in flight
     while eng.tick():
-        rings.append([id(b) for b in eng._staging])
-        for _, staged, _ in eng._in_flight:  # gathered into the ring
-            assert any(staged.base is b for b in eng._staging)
+        held.append(sorted(id(b) for b in [*eng._path._free,
+                                           *(b.buf for b in eng._in_flight)]))
     obsrt.disable()
     assert eng.ticks == 6
-    assert all(b is not None for b in eng._staging)
-    assert rings == [rings[0]] * 6           # allocated once, then reused
-    assert ob.metrics.total("engine_staging_reuses_total") == \
-        6 - len(eng._staging)
+    assert len(held[0]) == 2                 # two batches in flight at most
+    assert held == [held[0]] * 6             # allocated once, then reused
+    assert ob.metrics.total("engine_staging_reuses_total") == 6 - 2
     assert ob.metrics.total("engine_overlapped_launches_total") == 5
+
+
+def test_sharded_engine_refills_the_same_staging_buffers(qp8):
+    """Dispatches one at a time refill one staging buffer, and the logits
+    stay bitwise those of ResNetEngine."""
+    reqs, alone = _requests(6), _requests(6)
+    plain = ResNetEngine(R.RESNET8, qp8, batch=2, backend="lax-int")
+    for r in alone:
+        plain.submit(r)
+    plain.run()
+    eng = ShardedResNetEngine(R.RESNET8, qp8, batch=2, backend="lax-int",
+                              replicas=1, slack_ms=1.0, clock=FakeClock())
+    ob = obsrt.instrument()
+    for k in range(0, 6, 2):                 # a full bucket is due at once
+        eng.submit(reqs[k])
+        eng.submit(reqs[k + 1])
+        eng.run()
+        assert not eng._in_flight
+    obsrt.disable()
+    assert all(r.done for r in reqs)
+    assert len(eng._paths[0]._free) == 1
+    assert ob.metrics.total("engine_staging_reuses_total") == 2
+    np.testing.assert_array_equal(np.stack([r.logits for r in reqs]),
+                                  np.stack([r.logits for r in alone]))
 
 
 def test_sharded_engine_records_dispatch_and_harvest_nests(qp8):

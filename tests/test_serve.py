@@ -219,16 +219,22 @@ def test_staging_buffers_hold_each_batch_until_it_completes(qp8):
 
 
 def test_a_launch_with_every_staging_buffer_in_flight_raises(qp8, images):
+    """Three launches before any batch completes: a launch that finds every
+    staging buffer in flight takes a new one rather than refill a batch's,
+    so each batch holds its own buffer and its staged slice still equals
+    its images until it completes."""
     eng = ResNetEngine(R.RESNET8, qp8, batch=2, backend="lax-int")
     for r in _requests(images):
         eng.submit(r)
-    eng._launch(None)
-    eng._launch(None)                        # two batches in flight
-    with pytest.raises(RuntimeError, match="in flight"):
+    for _ in range(3):
         eng._launch(None)
-    assert len(eng.queue) == 2               # the queue is left as it was
-    for k, (_, staged, _) in enumerate(eng._in_flight):
-        np.testing.assert_array_equal(staged, images[2 * k:2 * k + 2])
+    assert not eng.queue
+    assert len({id(b.buf) for b in eng._in_flight}) == 3
+    for k, b in enumerate(eng._in_flight):
+        np.testing.assert_array_equal(b.buf[:len(b.reqs)],
+                                      images[2 * k:2 * k + 2])
+    assert eng.run() == 3 and eng.served == 6
+    assert len(eng._path._free) == 3         # every buffer freed once done
 
 
 # ---------------------------------------------------------------------------

@@ -209,6 +209,34 @@ def test_failed_dispatch_releases_accounting(qp8, images, monkeypatch):
     np.testing.assert_array_equal(np.stack([r.logits for r in good]), ref)
 
 
+def test_failed_dispatch_returns_its_staging_buffer(qp8, images,
+                                                    monkeypatch):
+    """A dispatch whose device execution errors frees its staging buffer,
+    and the next dispatch on that replica refills it, not a new one."""
+    import repro.serve.engine as E
+
+    eng = ShardedResNetEngine(R.RESNET8, qp8, batch=2, backend="lax-int",
+                              replicas=1, slack_ms=0.2)
+    path = eng._paths[0]
+    for i in range(2):
+        eng.submit(ImageRequest(rid=i, image=images[i]))
+    with monkeypatch.context() as m:
+        m.setattr(E.jax, "block_until_ready",
+                  lambda x: (_ for _ in ()).throw(RuntimeError("device died")))
+        with pytest.raises(RuntimeError, match="device died"):
+            eng.run()
+    freed = list(path._free)
+    assert len(freed) == 1
+    good = [ImageRequest(rid=10 + i, image=images[2 + i]) for i in range(2)]
+    for r in good:
+        eng.submit(r)
+    eng.run()
+    assert [id(b) for b in path._free] == [id(freed[0])]
+    np.testing.assert_array_equal(
+        np.stack([r.logits for r in good]),
+        np.asarray(R.int_forward(qp8, R.RESNET8, images[2:4])))
+
+
 # ---------------------------------------------------------------------------
 # async dispatch loop + backpressure
 # ---------------------------------------------------------------------------
